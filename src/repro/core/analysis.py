@@ -140,8 +140,7 @@ def analyze_step(
     if donate_argnums:
         jit_kwargs["donate_argnums"] = donate_argnums
     t0 = time.time()
-    from repro.parallel.mesh import mesh_context
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(fn, **jit_kwargs).lower(*args)
         compiled = lowered.compile()
     dt = time.time() - t0

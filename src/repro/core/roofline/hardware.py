@@ -96,6 +96,25 @@ TPU_V5E = ChipSpec(
 )
 
 
+# Peaks of the chips a measurement may run on, keyed by the name JAX
+# reports as ``device.device_kind``.  A device missing here is an error:
+# its peaks are unknown, and no other chip's stand in for them.
+CHIPS_BY_DEVICE_KIND: Mapping[str, ChipSpec] = {
+    "TPU v5 lite": TPU_V5E,
+}
+
+
+def chip_for_device_kind(device_kind: str) -> ChipSpec:
+    """The :class:`ChipSpec` of a device as JAX names it; raises for a kind
+    with no recorded peaks."""
+    try:
+        return CHIPS_BY_DEVICE_KIND[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peaks recorded for device kind {device_kind!r} "
+            f"(known: {sorted(CHIPS_BY_DEVICE_KIND)})") from None
+
+
 # The host this container runs on.  ``microbench.py`` *measures* the real
 # numbers with the paper's protocol; these are fallbacks so analysis is
 # deterministic when the microbench hasn't been run.

@@ -203,9 +203,9 @@ def paged_attention(q, k_pool, v_pool, block_tables, pos, *, scale,
                     sharded: bool = False, pipeline: Optional[str] = None):
     """Dispatching GQA paged-decode attention (see kernels/paged_attention).
 
-    q (B, KV, G, hd); pools (P, page, KV, hd); block_tables (B, n_blocks);
+    q (B, KV, G, hd); pools (P, KV, page, hd); block_tables (B, n_blocks);
     pos (B,).  Returns (B, KV, G, hd).  ``k_scale``/``v_scale``
-    (P, page, KV) float32 dequantize quantized pools (kernels/quantize.py)
+    (P, KV, 1, page) float32 dequantize quantized pools (kernels/quantize.py)
     — both backends apply the identical dequant, so the oracle contract
     holds on quantized caches.
     """
@@ -224,7 +224,7 @@ def mla_paged_attention(q_lat, q_rope, c_pool, r_pool, block_tables, pos, *,
 
     q_lat (B, H, r); q_rope (B, H, dr); pools (P, page, r) / (P, page, dr);
     block_tables (B, n_blocks); pos (B,).  Returns o_lat (B, H, r).
-    ``c_scale``/``r_scale`` (P, page) float32 dequantize quantized pools.
+    ``c_scale``/``r_scale`` (P, 1, page) float32 dequantize quantized pools.
     """
     impl = resolve("mla_paged_attention", backend, sharded=sharded,
                    pipeline=pipeline)
@@ -241,7 +241,7 @@ def paged_attention_verify(q, k_pool, v_pool, block_tables, pos, *, scale,
     """Dispatching GQA multi-token paged verification (spec decoding).
 
     q (B, T, KV, G, hd) — T draft-chain query tokens at positions
-    ``pos + t``; pools (P, page, KV, hd); block_tables (B, n_blocks);
+    ``pos + t``; pools (P, KV, page, hd); block_tables (B, n_blocks);
     pos (B,) first-query position.  Returns (B, T, KV, G, hd).
     """
     impl = resolve("paged_attention_verify", backend, sharded=sharded,

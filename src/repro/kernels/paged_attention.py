@@ -18,7 +18,11 @@ reference's gather traffic for the kernel's).
 
 Kernel layout (GQA):
   grid (num_slots, kv_heads, n_blocks); per grid step one (page, hd) K
-  slab and V slab of the mapped KV head are resident in VMEM.  The block
+  slab and V slab of the mapped KV head are resident in VMEM.  The pools
+  are head-major, (P, KV, page, hd), so that slab is the trailing two
+  dims of the pool — the only block shape Mosaic accepts for a single
+  head (a (page, 1, hd) block of a (P, page, KV, hd) pool is refused for
+  its unaligned second-minor dim).  The block
   table and per-slot positions ride in as *scalar prefetch* so the page
   -> HBM address mapping is known before the body runs — Pallas
   double-buffers the page fetches across the innermost grid dim, i.e. the
@@ -85,13 +89,17 @@ NEG_INF = -1e30
 
 # Quantized KV pools (kernels/quantize.py): every kernel and reference
 # below optionally takes float32 scale pools alongside the K/V (or latent)
-# pools — GQA scales (P, page, KV) per (page, line, kv_head), MLA scales
-# (P, page) per (page, line).  Dequantization is
-# ``values.astype(f32) * scale`` applied to each streamed slab BEFORE the
-# score matmul, the same op sequence in the Pallas walk and the jnp
-# gather, so the oracle stays byte-comparable.  Dequant happens in VMEM
-# after the (smaller) quantized page crossed HBM->VMEM — bandwidth-free
-# on the HBM level the decode roofline is bound by.
+# pools — GQA scales (P, KV, 1, page) per (page, kv_head, line), MLA
+# scales (P, 1, page) per (page, line).  The trailing (1, page) row is a
+# block Mosaic accepts (it equals the array's trailing dims), and a grid
+# step reads exactly its own head's page of scales; the kernel turns the
+# row into a (page, 1) column with an exact select (``_row_to_column``).
+# Dequantization is ``values.astype(f32) * scale`` applied to each
+# streamed slab BEFORE the score matmul, the same op sequence in the
+# Pallas walk and the jnp gather, so the oracle stays byte-comparable.
+# Dequant happens in VMEM after the (smaller) quantized page crossed
+# HBM->VMEM — bandwidth-free on the HBM level the decode roofline is
+# bound by.
 
 
 # --------------------------------------------------------------------------
@@ -100,17 +108,20 @@ NEG_INF = -1e30
 # --------------------------------------------------------------------------
 
 def _gather_kv(pool, scale_pool, block_tables, B, S, KV, hd):
-    """Gather pages to (B, S, KV, hd), dequantizing when a scale pool is
-    supplied (scale (P, page, KV) -> broadcast over hd)."""
-    g = pool[block_tables].reshape(B, S, KV, hd)
+    """Gather head-major pages (P, KV, page, hd) to (B, S, KV, hd),
+    dequantizing when a scale pool is supplied (scale (P, KV, 1, page) ->
+    broadcast over hd)."""
+    g = jnp.swapaxes(pool[block_tables], 2, 3).reshape(B, S, KV, hd)
     if scale_pool is None:
         return g
-    s = scale_pool[block_tables].reshape(B, S, KV)
+    s = jnp.swapaxes(scale_pool[block_tables][:, :, :, 0], 2, 3
+                     ).reshape(B, S, KV)
     return g.astype(jnp.float32) * s[..., None]
 
 
 def _gather_latent(pool, scale_pool, block_tables, B, S):
-    """Gather latent pages to (B, S, d), dequantizing when quantized."""
+    """Gather latent pages to (B, S, d), dequantizing when quantized
+    (scale (P, 1, page))."""
     g = pool[block_tables].reshape(B, S, -1)
     if scale_pool is None:
         return g
@@ -127,14 +138,13 @@ def paged_attention_reference(
 ) -> jax.Array:
     """GQA paged decode, gather-and-attend.
 
-    q (B, KV, G, hd); k/v pools (P, page, KV, hd); block_tables
+    q (B, KV, G, hd); k/v pools (P, KV, page, hd); block_tables
     (B, n_blocks); pos (B,) last written position.  Returns (B, KV, G, hd).
-    ``k_scale``/``v_scale`` (P, page, KV) float32 dequantize a quantized
-    pool before attending.
+    ``k_scale``/``v_scale`` (P, KV, 1, page) float32 dequantize a
+    quantized pool before attending.
     """
     B = q.shape[0]
-    KV, hd = k_pool.shape[2], k_pool.shape[3]
-    page_size = k_pool.shape[1]
+    _, KV, page_size, hd = k_pool.shape
     S = block_tables.shape[1] * page_size
     posb = pos.astype(jnp.int32)[:, None]                       # (B, 1)
     k = _gather_kv(k_pool, k_scale, block_tables, B, S, KV, hd)
@@ -161,12 +171,11 @@ def paged_attention_verify_reference(
     """GQA multi-token paged verification, gather-and-attend.
 
     q (B, T, KV, G, hd) — T query tokens per slot at positions
-    ``pos + t``; k/v pools (P, page, KV, hd); block_tables (B, n_blocks);
+    ``pos + t``; k/v pools (P, KV, page, hd); block_tables (B, n_blocks);
     pos (B,) position of the FIRST query token.  Returns (B, T, KV, G, hd).
     """
     B, T = q.shape[0], q.shape[1]
-    KV, hd = k_pool.shape[2], k_pool.shape[3]
-    page_size = k_pool.shape[1]
+    _, KV, page_size, hd = k_pool.shape
     S = block_tables.shape[1] * page_size
     k = _gather_kv(k_pool, k_scale, block_tables, B, S, KV, hd)
     v = _gather_kv(v_pool, v_scale, block_tables, B, S, KV, hd)
@@ -193,7 +202,7 @@ def mla_paged_attention_reference(
     q_lat (B, H, r) — q_nope already folded through wk_b; q_rope (B, H, dr);
     c/r pools (P, page, r) / (P, page, dr); pos (B,).  Returns o_lat
     (B, H, r) — the caller folds wv_b/wo back out.  ``c_scale``/``r_scale``
-    (P, page) float32 dequantize a quantized latent pool.
+    (P, 1, page) float32 dequantize a quantized latent pool.
     """
     B = q_lat.shape[0]
     page_size = c_pool.shape[1]
@@ -249,12 +258,23 @@ def _check_pipeline(pipeline: str) -> None:
         raise ValueError(f"pipeline {pipeline!r} not in {PIPELINES}")
 
 
+def _row_to_column(row: jax.Array) -> jax.Array:
+    """(1, n) scale row -> (n, 1) column through a diagonal select and a
+    lane sum: one nonzero term per row, so the value is exact, and no
+    unaligned transpose reaches Mosaic."""
+    n = row.shape[1]
+    sq = jnp.broadcast_to(row, (n, n))
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1))
+    return jnp.sum(jnp.where(eye, sq, 0.0), axis=1, keepdims=True)
+
+
 def _paged_decode_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
                          page_size: int, scale: float, soft_cap: float,
                          quantized: bool = False):
     """One (slot, kv_head, block) grid step of the GQA decode walk.  When
-    ``quantized`` two float32 scale slabs ((page,) for the mapped kv head)
-    follow k/v and dequantize the streamed page in VMEM."""
+    ``quantized`` two float32 (1, page) scale rows of the mapped head
+    follow k/v and dequantize the streamed slab in VMEM."""
     if quantized:
         ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
@@ -269,11 +289,11 @@ def _paged_decode_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0, 0].astype(jnp.float32)                     # (G, hd)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)               # (page, hd)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
+    k = k_ref[...].astype(jnp.float32)                      # (page, hd)
+    v = v_ref[...].astype(jnp.float32)
     if quantized:
-        k = k * ks_ref[0, :, 0][:, None]
-        v = v * vs_ref[0, :, 0][:, None]
+        k = k * _row_to_column(ks_ref[...])
+        v = v * _row_to_column(vs_ref[...])
     s = (q @ k.T) * scale                                   # (G, page)
     if soft_cap > 0:
         s = jnp.tanh(s / soft_cap) * soft_cap
@@ -295,6 +315,20 @@ def _paged_decode_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
                        jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
+def _gqa_page_specs(page_size: int, hd: int, quantized: bool):
+    """K, V (and scale) BlockSpecs of a (slot, kv_head, block) grid: one
+    head's (page, hd) slab of the head-major pools and its (1, page) scale
+    row, of the physical page the block table maps."""
+    kv = pl.BlockSpec((None, None, page_size, hd),
+                      lambda b, h, j, bt, ps: (bt[b, j], h, 0, 0))
+    specs = [kv, kv]
+    if quantized:
+        specs += [pl.BlockSpec((None, None, 1, page_size),
+                               lambda b, h, j, bt, ps: (bt[b, j], h, 0, 0))
+                  ] * 2
+    return specs
+
+
 def paged_attention(
     q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     block_tables: jax.Array, pos: jax.Array, *,
@@ -311,24 +345,17 @@ def paged_attention(
             q, k_pool, v_pool, block_tables, pos, n_group=G, scale=scale,
             soft_cap=soft_cap, k_scale=k_scale, v_scale=v_scale,
             interpret=interpret)
-    _, page_size, _, _ = k_pool.shape
+    page_size = k_pool.shape[2]
     n_blocks = block_tables.shape[1]
     quantized = k_scale is not None
     kernel = functools.partial(
         _paged_decode_kernel, page_size=page_size, scale=scale,
         soft_cap=soft_cap, quantized=quantized)
-    in_specs = [
-        pl.BlockSpec((1, 1, G, hd), lambda b, h, j, bt, ps: (b, h, 0, 0)),
-        pl.BlockSpec((1, page_size, 1, hd),
-                     lambda b, h, j, bt, ps: (bt[b, j], 0, h, 0)),
-        pl.BlockSpec((1, page_size, 1, hd),
-                     lambda b, h, j, bt, ps: (bt[b, j], 0, h, 0)),
-    ]
+    in_specs = [pl.BlockSpec((1, 1, G, hd),
+                             lambda b, h, j, bt, ps: (b, h, 0, 0))]
+    in_specs += _gqa_page_specs(page_size, hd, quantized)
     args = [block_tables, pos.astype(jnp.int32), q, k_pool, v_pool]
     if quantized:
-        in_specs += [pl.BlockSpec((1, page_size, 1),
-                                  lambda b, h, j, bt, ps: (bt[b, j], 0, h))
-                     ] * 2
         args += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                  # block tables + positions
@@ -373,8 +400,8 @@ def _mla_paged_decode_kernel(bt_ref, pos_ref, ql_ref, qr_ref, c_ref, r_ref,
     c = c_ref[0].astype(jnp.float32)                        # (page, r)
     kr = r_ref[0].astype(jnp.float32)                       # (page, dr)
     if quantized:
-        c = c * cs_ref[0][:, None]
-        kr = kr * rs_ref[0][:, None]
+        c = c * _row_to_column(cs_ref[...])
+        kr = kr * _row_to_column(rs_ref[...])
     s = (q_lat @ c.T + q_rope @ kr.T) * scale               # (H, page)
     k_pos = j * page_size + jax.lax.broadcasted_iota(
         jnp.int32, s.shape, 1)
@@ -392,6 +419,13 @@ def _mla_paged_decode_kernel(bt_ref, pos_ref, ql_ref, qr_ref, c_ref, r_ref,
     def _flush():
         o_ref[0] = (acc_ref[...] /
                     jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+
+
+def _mla_scale_spec(page_size: int):
+    """The (1, page) scale row of the mapped page, on a (slot, block)
+    grid."""
+    return pl.BlockSpec((None, 1, page_size),
+                        lambda b, j, bt, ps: (bt[b, j], 0, 0))
 
 
 def mla_paged_attention(
@@ -428,8 +462,7 @@ def mla_paged_attention(
     args = [block_tables, pos.astype(jnp.int32), q_lat, q_rope, c_pool,
             r_pool]
     if quantized:
-        in_specs += [pl.BlockSpec((1, page_size),
-                                  lambda b, j, bt, ps: (bt[b, j], 0))] * 2
+        in_specs += [_mla_scale_spec(page_size)] * 2
         args += [c_scale, r_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -474,11 +507,11 @@ def _paged_verify_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0, 0].astype(jnp.float32)                     # (T*G, hd)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)               # (page, hd)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
+    k = k_ref[...].astype(jnp.float32)                      # (page, hd)
+    v = v_ref[...].astype(jnp.float32)
     if quantized:
-        k = k * ks_ref[0, :, 0][:, None]
-        v = v * vs_ref[0, :, 0][:, None]
+        k = k * _row_to_column(ks_ref[...])
+        v = v * _row_to_column(vs_ref[...])
     s = (q @ k.T) * scale                                   # (T*G, page)
     if soft_cap > 0:
         s = jnp.tanh(s / soft_cap) * soft_cap
@@ -515,7 +548,7 @@ def paged_attention_verify(
     """
     _check_pipeline(pipeline)
     B, T, KV, G, hd = q.shape
-    page_size = k_pool.shape[1]
+    page_size = k_pool.shape[2]
     n_blocks = block_tables.shape[1]
     qf = q.transpose(0, 2, 1, 3, 4).reshape(B, KV, T * G, hd)
     if pipeline == "double":
@@ -528,19 +561,11 @@ def paged_attention_verify(
     kernel = functools.partial(
         _paged_verify_kernel, page_size=page_size, n_group=G, scale=scale,
         soft_cap=soft_cap, quantized=quantized)
-    in_specs = [
-        pl.BlockSpec((1, 1, T * G, hd),
-                     lambda b, h, j, bt, ps: (b, h, 0, 0)),
-        pl.BlockSpec((1, page_size, 1, hd),
-                     lambda b, h, j, bt, ps: (bt[b, j], 0, h, 0)),
-        pl.BlockSpec((1, page_size, 1, hd),
-                     lambda b, h, j, bt, ps: (bt[b, j], 0, h, 0)),
-    ]
+    in_specs = [pl.BlockSpec((1, 1, T * G, hd),
+                             lambda b, h, j, bt, ps: (b, h, 0, 0))]
+    in_specs += _gqa_page_specs(page_size, hd, quantized)
     args = [block_tables, pos.astype(jnp.int32), qf, k_pool, v_pool]
     if quantized:
-        in_specs += [pl.BlockSpec((1, page_size, 1),
-                                  lambda b, h, j, bt, ps:
-                                  (bt[b, j], 0, h))] * 2
         args += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -586,8 +611,8 @@ def _mla_paged_verify_kernel(bt_ref, pos_ref, ql_ref, qr_ref, c_ref, r_ref,
     c = c_ref[0].astype(jnp.float32)                        # (page, r)
     kr = r_ref[0].astype(jnp.float32)                       # (page, dr)
     if quantized:
-        c = c * cs_ref[0][:, None]
-        kr = kr * rs_ref[0][:, None]
+        c = c * _row_to_column(cs_ref[...])
+        kr = kr * _row_to_column(rs_ref[...])
     s = (q_lat @ c.T + q_rope @ kr.T) * scale               # (T*H, page)
     k_pos = j * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     t = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // n_heads
@@ -643,8 +668,7 @@ def mla_paged_attention_verify(
     ]
     args = [block_tables, pos.astype(jnp.int32), qlf, qrf, c_pool, r_pool]
     if quantized:
-        in_specs += [pl.BlockSpec((1, page_size),
-                                  lambda b, j, bt, ps: (bt[b, j], 0))] * 2
+        in_specs += [_mla_scale_spec(page_size)] * 2
         args += [c_scale, r_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -681,7 +705,7 @@ def _gqa_double_kernel(bt_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest,
     token t = r // n_group (t = 0 everywhere for single-token decode) —
     the per-block compute is the exact op sequence of the single-buffered
     kernels, so the output is bit-identical to ``pipeline="off"``.
-    Quantized pools add two (page,) f32 scale slabs that ride the same
+    Quantized pools add two (1, page) f32 scale rows that ride the same
     one-block lookahead; the dequant multiply sits at the identical op
     position as the single-buffered kernel's, keeping the bit-identity."""
     if quantized:
@@ -693,20 +717,18 @@ def _gqa_double_kernel(bt_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest,
 
     def k_dma(slot, j):
         return pltpu.make_async_copy(
-            k_hbm.at[bt_ref[b, j], :, h, :], k_slab.at[slot],
-            k_sem.at[slot])
+            k_hbm.at[bt_ref[b, j], h], k_slab.at[slot], k_sem.at[slot])
 
     def v_dma(slot, j):
         return pltpu.make_async_copy(
-            v_hbm.at[bt_ref[b, j], :, h, :], v_slab.at[slot],
-            v_sem.at[slot])
+            v_hbm.at[bt_ref[b, j], h], v_slab.at[slot], v_sem.at[slot])
 
     def scale_dmas(slot, j):
         return (pltpu.make_async_copy(
-                    ks_hbm.at[bt_ref[b, j], :, h], ks_slab.at[slot],
+                    ks_hbm.at[bt_ref[b, j], h], ks_slab.at[slot],
                     ks_sem.at[slot]),
                 pltpu.make_async_copy(
-                    vs_hbm.at[bt_ref[b, j], :, h], vs_slab.at[slot],
+                    vs_hbm.at[bt_ref[b, j], h], vs_slab.at[slot],
                     vs_sem.at[slot]))
 
     k_dma(0, 0).start()
@@ -736,8 +758,8 @@ def _gqa_double_kernel(bt_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest,
         if quantized:
             for dma in scale_dmas(slot, j):
                 dma.wait()
-            k = k * ks_slab[slot][:, None]
-            v = v * vs_slab[slot][:, None]
+            k = k * _row_to_column(ks_slab[slot])
+            v = v * _row_to_column(vs_slab[slot])
         s = (q @ k.T) * scale                               # (rows, page)
         if soft_cap > 0:
             s = jnp.tanh(s / soft_cap) * soft_cap
@@ -768,7 +790,7 @@ def _gqa_paged_double(qf: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                       interpret: bool) -> jax.Array:
     """qf (B, KV, rows, hd) flattened queries -> (B, KV, rows, hd)."""
     B, KV, rows, hd = qf.shape
-    page_size = k_pool.shape[1]
+    page_size = k_pool.shape[2]
     n_blocks = block_tables.shape[1]
     quantized = k_scale is not None
     kernel = functools.partial(
@@ -778,8 +800,8 @@ def _gqa_paged_double(qf: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     in_specs = [
         pl.BlockSpec((1, 1, rows, hd),
                      lambda b, h, bt, ps: (b, h, 0, 0)),
-        pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-        pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+        pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+        pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
     ]
     args = [block_tables, pos.astype(jnp.int32), qf, k_pool, v_pool]
     scratch = [
@@ -788,9 +810,9 @@ def _gqa_paged_double(qf: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     ]
     sems = [pltpu.SemaphoreType.DMA((2,))] * 2
     if quantized:
-        in_specs += [pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)] * 2
+        in_specs += [pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)] * 2
         args += [k_scale, v_scale]
-        scratch += [pltpu.VMEM((2, page_size), jnp.float32)] * 2
+        scratch += [pltpu.VMEM((2, 1, page_size), jnp.float32)] * 2
         sems += [pltpu.SemaphoreType.DMA((2,))] * 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -814,7 +836,7 @@ def _mla_double_kernel(bt_ref, pos_ref, ql_ref, qr_ref, c_hbm, r_hbm,
     """Grid (B,): the latent block walk with two (page, r) + (page, dr)
     slabs and a one-block DMA lookahead.  Row r of the flattened query
     slabs belongs to draft token t = r // n_heads (0 for decode).
-    Quantized pools add two (page,) f32 scale slabs on the same
+    Quantized pools add two (1, page) f32 scale slabs on the same
     lookahead; dequant sits at the single-buffered kernel's op position
     so the output stays bit-identical to ``pipeline="off"``."""
     if quantized:
@@ -868,8 +890,8 @@ def _mla_double_kernel(bt_ref, pos_ref, ql_ref, qr_ref, c_hbm, r_hbm,
         if quantized:
             for dma in scale_dmas(slot, j):
                 dma.wait()
-            c = c * cs_slab[slot][:, None]
-            kr = kr * rs_slab[slot][:, None]
+            c = c * _row_to_column(cs_slab[slot])
+            kr = kr * _row_to_column(rs_slab[slot])
         s = (q_lat @ c.T + q_rope @ kr.T) * scale           # (rows, page)
         k_pos = j * page_size + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 1)
@@ -908,8 +930,8 @@ def _mla_paged_double(qlf: jax.Array, qrf: jax.Array, c_pool: jax.Array,
     in_specs = [
         pl.BlockSpec((1, rows, r), lambda b, bt, ps: (b, 0, 0)),
         pl.BlockSpec((1, rows, dr), lambda b, bt, ps: (b, 0, 0)),
-        pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-        pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+        pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+        pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
     ]
     args = [block_tables, pos.astype(jnp.int32), qlf, qrf, c_pool, r_pool]
     scratch = [
@@ -918,9 +940,9 @@ def _mla_paged_double(qlf: jax.Array, qrf: jax.Array, c_pool: jax.Array,
     ]
     sems = [pltpu.SemaphoreType.DMA((2,))] * 2
     if quantized:
-        in_specs += [pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)] * 2
+        in_specs += [pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)] * 2
         args += [c_scale, r_scale]
-        scratch += [pltpu.VMEM((2, page_size), jnp.float32)] * 2
+        scratch += [pltpu.VMEM((2, 1, page_size), jnp.float32)] * 2
         sems += [pltpu.SemaphoreType.DMA((2,))] * 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,

@@ -27,7 +27,6 @@ from repro.launch.mesh import make_production_mesh, mesh_name
 from repro.launch import specs as specs_mod
 from repro.models import decode_step, loss_fn, prefill
 from repro.models.common import SHAPES, applicable_shapes
-from repro.parallel.mesh import mesh_context
 from repro.parallel.sharding import sharding_context
 from repro.train.step import TrainConfig, make_train_step
 
@@ -115,7 +114,7 @@ def run_cell(arch: str, shape: str, mesh, *, verbose: bool = True,
                 args, in_sh, _ = specs_mod.decode_specs(cfg, cell, mesh)
                 fn = jax.jit(lambda p, c, t, pos: decode_step(p, cfg, c, t, pos),
                              in_shardings=in_sh, donate_argnums=(1,))
-            with mesh_context(mesh):
+            with jax.set_mesh(mesh):
                 lowered = fn.lower(*args)
                 compiled = lowered.compile()
         compile_s = time.time() - t0

@@ -6,28 +6,18 @@ state.  Single pod: 16x16 = 256 chips (data, model).  Multi-pod: 2 pods of
 
 When the process exposes more devices than a mesh needs (the dry-run forces
 512 host devices and then builds the single-pod 256-chip mesh), the first
-``prod(shape)`` devices are used explicitly — ``jax.make_mesh`` would
-otherwise insist on consuming every device.
+``prod(shape)`` devices are used (parallel.mesh.make_mesh).
 """
 
 from __future__ import annotations
 
-import numpy as np
-
-import jax
+from repro.parallel.mesh import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    n = int(np.prod(shape))
-    devices = jax.devices()[:n]
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, devices=devices,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
-        )
-    return jax.make_mesh(shape, axes, devices=devices)
+    return make_mesh(shape, axes)
 
 
 def mesh_name(mesh) -> str:

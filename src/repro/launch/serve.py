@@ -34,11 +34,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ALL_ARCHS, get_config, smoke
-from repro.core.roofline.hardware import HOST_CPU_FALLBACK, TPU_V5E
+from repro.core.roofline.hardware import (HOST_CPU_FALLBACK, TPU_V5E,
+                                          chip_for_device_kind)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
+from repro.parallel.mesh import make_host_mesh
 from repro.serve import (EngineConfig, GenerateConfig, SpecConfig,
-                         make_engine, parse_mesh, supports_paging,
-                         supports_spec, tp_sharding_error)
+                         init_sharded_params, make_engine, parse_mesh,
+                         supports_paging, supports_spec, tp_sharding_error)
 from repro.serve.crosscheck import capacity_report
 from repro.serve.spec import speculative_summary
 
@@ -82,7 +85,9 @@ def main():
                     help="decode slots (0 = one per request)")
     ap.add_argument("--page-size", type=int, default=16)
     ap.add_argument("--prefill-chunk", type=int, default=0)
-    ap.add_argument("--chip", choices=["host", "tpu_v5e"], default="tpu_v5e")
+    ap.add_argument("--chip", choices=["host", "tpu_v5e"], default="tpu_v5e",
+                    help="roofline peaks off-TPU; on a TPU the peaks of "
+                         "the device JAX reports are used")
     ap.add_argument("--backend", choices=["auto", "pallas", "jnp"],
                     default=None,
                     help="paged-attention kernel backend (kernels/ops.py "
@@ -129,6 +134,7 @@ def main():
                     help="enable telemetry and write a Prometheus "
                          "text-exposition metrics snapshot here")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:
@@ -138,8 +144,19 @@ def main():
         err = tp_sharding_error(cfg, mesh_shape[1])
         if err:
             raise SystemExit(f"--mesh {args.mesh}: {err}")
-    params = init_params(cfg, jax.random.key(0))
-    chip = TPU_V5E if args.chip == "tpu_v5e" else HOST_CPU_FALLBACK
+    if mesh_shape[0] == 1 and mesh_shape[1] > 1:
+        # weights born in their shards: a model larger than one chip
+        # never lands whole on device 0
+        params = init_sharded_params(cfg, jax.random.key(0),
+                                     make_host_mesh(data=1,
+                                                    model=mesh_shape[1]))
+    else:
+        params = init_params(cfg, jax.random.key(0))
+    dev = jax.devices()[0]
+    if dev.platform == "tpu":
+        chip = chip_for_device_kind(dev.device_kind)
+    else:
+        chip = TPU_V5E if args.chip == "tpu_v5e" else HOST_CPU_FALLBACK
     slots = args.slots or args.batch
     ecfg = EngineConfig(
         num_slots=slots, page_size=args.page_size,

@@ -22,7 +22,7 @@ from repro.core.roofline import scope_for_mesh
 from repro.core.roofline.hardware import HOST_CPU_FALLBACK
 from repro.launch import specs as specs_mod
 from repro.models.common import ShapeCell, model_flops
-from repro.parallel.mesh import make_host_mesh, mesh_context
+from repro.parallel.mesh import make_host_mesh
 from repro.parallel.sharding import sharding_context
 from repro.train import (CheckpointManager, LoopConfig, OptConfig,
                          SyntheticLMData, TrainConfig, TrainLoop,
@@ -63,7 +63,7 @@ def main():
         cell = ShapeCell("preflight", args.seq, args.batch, "train")
         spec_args, in_sh, out_sh = specs_mod.train_specs(cfg, cell, mesh)
         step = make_train_step(cfg, tcfg)
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             compiled = jax.jit(step, in_shardings=in_sh,
                                out_shardings=out_sh,
                                donate_argnums=(0,)).lower(*spec_args).compile()

@@ -176,50 +176,64 @@ def init_cache_defs(cfg: ModelConfig, batch: int, max_len: int) -> Dict[str, Par
 
 def paged_pool_defs(cfg: ModelConfig, num_pages: int, page_size: int
                     ) -> Dict[str, ParamDef]:
-    """Physical page pool for the GQA KV cache: (num_pages, page_size, KV, hd).
+    """Physical page pool for the GQA KV cache, head-major:
+    (num_pages, KV, page_size, hd).
 
     Pages carry no batch dim — a per-slot block table maps logical block
     index -> physical page, so slots of different lengths share one pool
-    (vLLM-style paging; the block table is shared across layers).
+    (vLLM-style paging; the block table is shared across layers).  Heads
+    sit ahead of the page lines so one head's (page, hd) slab is the
+    trailing two dims of the pool: the block the Pallas page walk reads
+    per grid step (kernels/paged_attention.py).
 
     With ``cfg.kv_dtype`` quantized (int8 / fp8_e4m3) the k/v pools store
-    quantized values plus float32 absmax scales per (page, line, kv_head).
-    Scales carry the same ``kv_seq``/``kv_heads`` logical axes as the
-    pools minus ``head_dim``, so under tensor parallelism they shard WITH
-    the kv heads and every page lifecycle op (CoW, swap, migration) treats
-    them as just another paged leaf."""
+    quantized values plus float32 absmax scales per (page, kv_head, line),
+    laid out (num_pages, KV, 1, page_size) so one head's scales of a page
+    are a (1, page) block the page walk reads alongside its slab.  Scales
+    carry the pools' ``kv_heads``/``kv_seq`` logical axes, so under tensor
+    parallelism they shard WITH the kv heads and every page lifecycle op
+    (CoW, swap, migration) treats them as just another paged leaf."""
     KV, hd = cfg.n_kv_heads, cfg.hd
     store = kvq.store_dtype(cfg.kv_dtype, cfg.dtype)
     defs = {
-        "k": ParamDef((num_pages, page_size, KV, hd),
-                      ("none", "kv_seq", "kv_heads", "head_dim"), store,
+        "k": ParamDef((num_pages, KV, page_size, hd),
+                      ("none", "kv_heads", "kv_seq", "head_dim"), store,
                       init="zeros"),
-        "v": ParamDef((num_pages, page_size, KV, hd),
-                      ("none", "kv_seq", "kv_heads", "head_dim"), store,
+        "v": ParamDef((num_pages, KV, page_size, hd),
+                      ("none", "kv_heads", "kv_seq", "head_dim"), store,
                       init="zeros"),
     }
     if kvq.is_quantized(cfg.kv_dtype):
         for name in ("k_scale", "v_scale"):
-            defs[name] = ParamDef((num_pages, page_size, KV),
-                                  ("none", "kv_seq", "kv_heads"), "float32",
-                                  init="ones")
+            defs[name] = ParamDef((num_pages, KV, 1, page_size),
+                                  ("none", "kv_heads", "none", "kv_seq"),
+                                  "float32", init="ones")
     return defs
 
 
 def _commit_kv(pool: Dict[str, jax.Array], name: str, blk, off, new,
                cfg: ModelConfig) -> Dict[str, jax.Array]:
-    """Write new K or V lines into the page pool, quantizing on the way in
-    when the pool is quantized.  ``new`` (..., KV, hd) indexed by
-    ``blk``/``off`` of matching leading shape; returns the updated leaves
-    ({name} and, when quantized, {name}_scale)."""
+    """Write new K or V lines into the head-major page pool, quantizing on
+    the way in when the pool is quantized.  ``new`` (..., KV, hd) indexed
+    by ``blk``/``off`` of matching leading shape; returns the updated
+    leaves ({name} and, when quantized, {name}_scale)."""
     out = {}
     if f"{name}_scale" in pool:
-        q, s = kvq.quantize(new, cfg.kv_dtype, -1)
-        out[name] = pool[name].at[blk, off].set(q)
-        out[f"{name}_scale"] = pool[f"{name}_scale"].at[blk, off].set(s)
-    else:
-        out[name] = pool[name].at[blk, off].set(new.astype(pool[name].dtype))
+        new, s = kvq.quantize(new, cfg.kv_dtype, -1)
+        out[f"{name}_scale"] = pool[f"{name}_scale"].at[blk, :, 0, off].set(s)
+    out[name] = pool[name].at[blk, :, off].set(new.astype(pool[name].dtype))
     return out
+
+
+def _gather_pages(pool: Dict[str, jax.Array], name: str, block_table,
+                  cfg: ModelConfig) -> jax.Array:
+    """One slot's K or V pages as a (S, KV, hd) sequence in the model
+    dtype, dequantized when the pool is quantized."""
+    g = jnp.swapaxes(pool[name][block_table], 1, 2)     # (nb, page, KV, hd)
+    if f"{name}_scale" in pool:
+        s = jnp.swapaxes(pool[f"{name}_scale"][block_table][:, :, 0], 1, 2)
+        g = kvq.dequantize(g, s).astype(cfg.dtype)
+    return g.reshape(-1, *g.shape[2:])
 
 
 def decode_attention_paged(
@@ -229,7 +243,7 @@ def decode_attention_paged(
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """One-token decode for every slot against a paged pool.
 
-    x (B,1,D); pool k/v (P, page, KV, hd); block_tables (B, n_blocks)
+    x (B,1,D); pool k/v (P, KV, page, hd); block_tables (B, n_blocks)
     logical block -> physical page; pos (B,) per-slot write position.
     Inactive slots must map to a reserved trash page (their writes collide
     harmlessly) and are masked out by the caller.
@@ -340,18 +354,10 @@ def prefill_attention_paged(
             **_commit_kv(pool, "k", blk, off, k_new[0], cfg),
             **_commit_kv(pool, "v", blk, off, v_new[0], cfg)}
     S = block_table.shape[0] * page_size
-    if "k_scale" in pool:
-        # chunked prefill re-reads earlier chunks through the quantized
-        # pages — the same dequantized values every later decode step sees
-        k = kvq.dequantize(pool["k"][block_table],
-                           pool["k_scale"][block_table]).astype(cfg.dtype)
-        v = kvq.dequantize(pool["v"][block_table],
-                           pool["v_scale"][block_table]).astype(cfg.dtype)
-        k = k.reshape(1, S, KV, hd)
-        v = v.reshape(1, S, KV, hd)
-    else:
-        k = pool["k"][block_table].reshape(1, S, KV, hd)
-        v = pool["v"][block_table].reshape(1, S, KV, hd)
+    # chunked prefill re-reads earlier chunks through the (quantized)
+    # pages — the same values every later decode step sees
+    k = _gather_pages(pool, "k", block_table, cfg)[None]
+    v = _gather_pages(pool, "v", block_table, cfg)[None]
     q = q.reshape(B, T, KV, G, hd)
     k_pos = jnp.arange(S, dtype=jnp.int32)[None, :]
     o = _attn_core(q, k, v, idx[None, :], k_pos, causal=True,

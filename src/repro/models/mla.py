@@ -154,8 +154,10 @@ def mla_paged_pool_defs(cfg: ModelConfig, num_pages: int, page_size: int
 
     With ``cfg.kv_dtype`` quantized the latent/rope pools store quantized
     values plus a float32 absmax scale per (page, line) — the latent
-    vector is one quantization group.  Latent pools replicate under TP, so
-    the per-line scales do too."""
+    vector is one quantization group.  The scales are laid out
+    (num_pages, 1, page) so a page's scales are one (1, page) block the
+    Pallas walk can read.  Latent pools replicate under TP, so the
+    per-line scales do too."""
     store = kvq.store_dtype(cfg.kv_dtype, cfg.dtype)
     defs = {
         "c_kv": ParamDef((num_pages, page_size, cfg.kv_lora_rank),
@@ -165,8 +167,9 @@ def mla_paged_pool_defs(cfg: ModelConfig, num_pages: int, page_size: int
     }
     if kvq.is_quantized(cfg.kv_dtype):
         for name in ("c_kv_scale", "k_rope_scale"):
-            defs[name] = ParamDef((num_pages, page_size),
-                                  ("none", "kv_seq"), "float32", init="ones")
+            defs[name] = ParamDef((num_pages, 1, page_size),
+                                  ("none", "none", "kv_seq"), "float32",
+                                  init="ones")
     return defs
 
 
@@ -176,12 +179,21 @@ def _commit_latent(pool: Dict[str, jax.Array], name: str, blk, off, new,
     way in when the pool is quantized (see attention._commit_kv)."""
     out = {}
     if f"{name}_scale" in pool:
-        q, s = kvq.quantize(new, cfg.kv_dtype, -1)
-        out[name] = pool[name].at[blk, off].set(q)
-        out[f"{name}_scale"] = pool[f"{name}_scale"].at[blk, off].set(s)
-    else:
-        out[name] = pool[name].at[blk, off].set(new.astype(pool[name].dtype))
+        new, s = kvq.quantize(new, cfg.kv_dtype, -1)
+        out[f"{name}_scale"] = pool[f"{name}_scale"].at[blk, 0, off].set(s)
+    out[name] = pool[name].at[blk, off].set(new.astype(pool[name].dtype))
     return out
+
+
+def _gather_latent_pages(pool: Dict[str, jax.Array], name: str,
+                         block_table, cfg: ModelConfig) -> jax.Array:
+    """One slot's latent (or rope) pages as a (1, S, d) sequence in the
+    model dtype, dequantized when the pool is quantized."""
+    g = pool[name][block_table]                          # (nb, page, d)
+    if f"{name}_scale" in pool:
+        g = kvq.dequantize(g, pool[f"{name}_scale"][block_table][:, 0]
+                           ).astype(cfg.dtype)
+    return g.reshape(1, -1, g.shape[-1])
 
 
 def _mla_attend(p, q_nope, q_rope, c_kv, k_rope, valid, cfg: ModelConfig):
@@ -335,16 +347,8 @@ def mla_prefill_paged(p, x: jax.Array, pool: Dict[str, jax.Array],
             **_commit_latent(pool, "c_kv", blk, off, c_new[0], cfg),
             **_commit_latent(pool, "k_rope", blk, off, kr_new[0], cfg)}
     S = block_table.shape[0] * page_size
-    if "c_kv_scale" in pool:
-        c_kv = kvq.dequantize(pool["c_kv"][block_table],
-                              pool["c_kv_scale"][block_table]
-                              ).astype(cfg.dtype).reshape(1, S, -1)
-        k_rope = kvq.dequantize(pool["k_rope"][block_table],
-                                pool["k_rope_scale"][block_table]
-                                ).astype(cfg.dtype).reshape(1, S, -1)
-    else:
-        c_kv = pool["c_kv"][block_table].reshape(1, S, -1)
-        k_rope = pool["k_rope"][block_table].reshape(1, S, -1)
+    c_kv = _gather_latent_pages(pool, "c_kv", block_table, cfg)
+    k_rope = _gather_latent_pages(pool, "k_rope", block_table, cfg)
     valid = (idx[:, None] >= jnp.arange(S, dtype=jnp.int32)[None, :])[None]
     out = _mla_attend(p, q_nope, q_rope, c_kv, k_rope, valid, cfg)
     out = constrain(out, "batch", "seq", "d_model")

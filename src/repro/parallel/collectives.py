@@ -20,7 +20,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def row_parallel_psum(partial: jax.Array, axis: str) -> jax.Array:
@@ -159,7 +159,7 @@ def ring_allgather_matmul(x: jax.Array, w: jax.Array, mesh: Mesh,
         body, mesh=mesh,
         in_specs=(P(axis, None), P(None, axis)),
         out_specs=P(None, axis),
-        check_rep=False,
+        check_vma=False,
     )(x, w)
     if s_pad != S or n_pad != N:
         out = out[:S, :N]
@@ -194,7 +194,7 @@ def psum_scatter_matmul(x: jax.Array, w: jax.Array, mesh: Mesh,
         body, mesh=mesh,
         in_specs=(P(None, axis), P(axis, None)),
         out_specs=P(None, axis),
-        check_rep=False,
+        check_vma=False,
     )(x, w)
     if n_pad != N:
         out = out[:, :N]

@@ -23,23 +23,13 @@ DATA_AXIS = "data"
 POD_AXIS = "pod"
 
 
-def mesh_context(mesh: jax.sharding.Mesh):
-    """``jax.set_mesh(mesh)`` where it exists (jax >= 0.6); the plain Mesh
-    context manager on older jax — same named-sharding resolution."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
 def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> jax.sharding.Mesh:
-    """Build a mesh without tripping the jax-0.9 axis_types deprecation
-    (older jax has neither AxisType nor the axis_types kwarg)."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            tuple(shape), tuple(axes),
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
-        )
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """An Auto-typed mesh over the first ``prod(shape)`` devices, so a mesh
+    smaller than the host (one chip of four) can be built."""
+    n = int(np.prod(shape))
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         devices=jax.devices()[:n],
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1, pod: int = 1) -> jax.sharding.Mesh:

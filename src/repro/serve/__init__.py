@@ -7,8 +7,8 @@ from .kv_cache import (PagedKVCache, SwapSnapshot, supports_paging,
 from .proposer import DraftModelProposer, NgramProposer, Proposal
 from .router import Router
 from .scheduler import Request, RequestState, RooflineLedger, Scheduler
-from .shard import (ShardedEngine, ShardedSpecEngine, make_engine,
-                    parse_mesh, supports_tp, tp_local_config,
+from .shard import (ShardedEngine, ShardedSpecEngine, init_sharded_params,
+                    make_engine, parse_mesh, supports_tp, tp_local_config,
                     tp_sharding_error)
 from .spec import (SpecConfig, SpecEngine, adaptive_k,
                    spec_expected_tokens_per_pass, spec_speedup_model,
@@ -22,7 +22,8 @@ __all__ = [
     "supports_prefix_cache",
     "Request", "RequestState", "RooflineLedger", "Scheduler",
     "DraftModelProposer", "NgramProposer", "Proposal",
-    "ShardedEngine", "ShardedSpecEngine", "make_engine", "parse_mesh",
+    "ShardedEngine", "ShardedSpecEngine", "init_sharded_params",
+    "make_engine", "parse_mesh",
     "supports_tp", "tp_local_config", "tp_sharding_error",
     "SpecConfig", "SpecEngine", "adaptive_k",
     "spec_expected_tokens_per_pass", "spec_speedup_model", "supports_spec",

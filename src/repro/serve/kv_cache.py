@@ -4,8 +4,11 @@ Storage layout (vLLM-style paging adapted to the scan-over-superblocks
 cache pytrees):
 
 * Attention / MLA cache leaves become batchless *page pools* of shape
-  ``(reps, num_pages, page_size, ...)`` — one pool per stacked cache leaf,
-  all layers addressed through the same per-slot block table.
+  ``(reps, num_pages, ...)`` with the page-line axis (logical ``kv_seq``)
+  somewhere after the page axis — ``(reps, P, page, r)`` for the MLA
+  latent, head-major ``(reps, P, KV, page, hd)`` for GQA K/V — one pool
+  per stacked cache leaf, all layers addressed through the same per-slot
+  block table.
 * O(1) recurrent states (mamba ``h``/``conv``, mLSTM ``C/n/m``, sLSTM
   ``c/n/h/m``) stay per-slot rows ``(reps, num_slots, ...)`` — a recurrent
   "page" is just the slot row.
@@ -173,10 +176,14 @@ class PagedKVCache:
         self.pools = tree_instantiate(defs, key if key is not None
                                       else jax.random.key(0))
         # leaf -> is it a page pool (vs a per-slot state row)?  Pool leaves
-        # carry "kv_seq" but no "batch" logical axis after stacking.
+        # carry "kv_seq" but no "batch" logical axis after stacking; the
+        # position of "kv_seq" is the leaf's page-line axis.
         self._paged = jax.tree.map(
             lambda d: "kv_seq" in d.logical and "batch" not in d.logical,
             defs, is_leaf=_is_def)
+        self._line_axis = jax.tree.map(
+            lambda d: d.logical.index("kv_seq") if "kv_seq" in d.logical
+            else 0, defs, is_leaf=_is_def)
 
         self.block_tables = np.zeros((num_slots, self.blocks_per_slot),
                                      np.int32)
@@ -585,16 +592,25 @@ class PagedKVCache:
         off = jnp.asarray(idx % self.page_size)
         states = self._quantize_states(states)
 
-        def f(pool, state, paged):
-            if paged:
-                return pool.at[:, phys, off].set(
-                    state[:, 0, start:prompt_len].astype(pool.dtype))
-            return jax.lax.dynamic_update_slice_in_dim(
-                pool, state.astype(pool.dtype), slot, axis=1)
+        def f(pool, state, paged, a):
+            if not paged:
+                return jax.lax.dynamic_update_slice_in_dim(
+                    pool, state.astype(pool.dtype), slot, axis=1)
+            # state (reps, 1, S, *line) -> the (reps, n, *rest) values of
+            # pool[:, phys, ..., off (axis a), ...]; with the two indexed
+            # axes apart, numpy indexing puts the token axis first
+            rest = pool.shape[2:a] + pool.shape[a + 1:]
+            val = state[:, 0, start:prompt_len].astype(pool.dtype)
+            val = val.reshape(val.shape[:2] + rest)
+            if a > 2:
+                val = jnp.moveaxis(val, 1, 0)
+            idx = [slice(None)] * pool.ndim
+            idx[1], idx[a] = phys, off
+            return pool.at[tuple(idx)].set(val)
 
         for i, (seg_pool, seg_state) in enumerate(zip(self.pools, states)):
             self.pools[i] = jax.tree.map(f, seg_pool, seg_state,
-                                         self._paged[i])
+                                         self._paged[i], self._line_axis[i])
 
     def _quantize_states(self, states: List[Any]) -> List[Any]:
         """Quantized pools (cfg.kv_dtype != bf16) carry per-line scale
@@ -631,16 +647,17 @@ class PagedKVCache:
         """
         row = jnp.asarray(self.block_tables[slot])
 
-        def f(pool, paged):
+        def f(pool, paged, a):
             if paged:
-                g = pool[:, row]                    # (reps, blocks, page, ...)
+                g = jnp.moveaxis(pool[:, row], a, 2)   # (reps, nb, page, ...)
                 return g.reshape(g.shape[0], 1,
                                  self.blocks_per_slot * self.page_size,
                                  *g.shape[3:])[:, :, : self.max_len]
             return jax.lax.dynamic_slice_in_dim(pool, slot, 1, axis=1)
 
-        dense = [jax.tree.map(f, seg, flag)
-                 for seg, flag in zip(self.pools, self._paged)]
+        dense = [jax.tree.map(f, seg, flag, axes)
+                 for seg, flag, axes in zip(self.pools, self._paged,
+                                            self._line_axis)]
         if kvq.is_quantized(self.cfg.kv_dtype):
             for seg in dense:
                 for blk in seg.values():

@@ -53,9 +53,9 @@ from typing import Any, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
-from repro.models import model_param_defs, paged_cache_defs
+from repro.models import init_params, model_param_defs, paged_cache_defs
 from repro.models.common import ModelConfig
 from repro.parallel import sharding as shd
 from repro.parallel.mesh import (MODEL_AXIS, make_host_mesh,
@@ -145,6 +145,17 @@ def param_pspecs(cfg: ModelConfig, mesh) -> Any:
                            shd.DECODE_TP_RULES)
     specs["embed"]["tok"] = P()
     return specs
+
+
+def init_sharded_params(cfg: ModelConfig, key: jax.Array, mesh) -> Any:
+    """Random weights created directly in their tensor-parallel
+    ``NamedSharding``s on ``mesh``: each device materializes only its own
+    shard, so a model larger than one chip never lands whole on device 0.
+    :class:`ShardedEngine` then finds them already placed."""
+    shardings = jax.tree.map(lambda sp: NamedSharding(mesh, sp),
+                             param_pspecs(cfg, mesh))
+    return jax.jit(lambda k: init_params(cfg, k),
+                   out_shardings=shardings)(key)
 
 
 def pool_pspecs(cfg: ModelConfig, num_slots: int, num_pages: int,
@@ -259,14 +270,14 @@ class _ShardedStepMixin:
         self._decode_fn = jax.jit(shard_map(
             self._decode_callable(self.cfg_local), mesh=self.mesh,
             in_specs=(self._param_specs, self._pool_specs) + (rep,) * 9,
-            out_specs=(rep, self._pool_specs), check_rep=False))
+            out_specs=(rep, self._pool_specs), check_vma=False))
         if isinstance(self, SpecEngine):
             n_rep_in = 12 if self.scfg.proposer == "draft" else 11
             self._verify_fn = jax.jit(shard_map(
                 self._verify_callable(self.cfg_local), mesh=self.mesh,
                 in_specs=(self._param_specs, self._pool_specs)
                 + (rep,) * n_rep_in,
-                out_specs=(rep, rep, self._pool_specs), check_rep=False))
+                out_specs=(rep, rep, self._pool_specs), check_vma=False))
 
     # -- crosscheck support ------------------------------------------------
 

@@ -87,7 +87,7 @@ def test_ring_matmul_reduce_matches_psum():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.parallel.mesh import make_mesh
         from repro.parallel.collectives import (ring_matmul_reduce,
                                                 row_parallel_matmul,
@@ -108,7 +108,7 @@ def test_ring_matmul_reduce_matches_psum():
 
             specs = dict(mesh=mesh, in_specs=(P(None, None, "model"),
                                               P("model", None)),
-                         out_specs=P(), check_rep=False)
+                         out_specs=P(), check_vma=False)
             want = jax.jit(shard_map(blocking, **specs))(h, w)
             got = jax.jit(shard_map(ring, **specs))(h, w)
             np.testing.assert_allclose(np.asarray(got), np.asarray(want),

@@ -111,7 +111,7 @@ def test_kv_fallback_compiles_on_asymmetric_mesh():
         from repro.launch import specs as specs_mod
         from repro.models import decode_step
         from repro.models.common import ShapeCell
-        from repro.parallel.mesh import make_mesh, mesh_context
+        from repro.parallel.mesh import make_mesh
         from repro.parallel.sharding import sharding_context
 
         cfg = smoke(get_config("qwen3-0.6b"))  # kv=2 < model axis 4
@@ -121,7 +121,7 @@ def test_kv_fallback_compiles_on_asymmetric_mesh():
             args, in_sh, _ = specs_mod.decode_specs(cfg, cell, mesh)
             fn = jax.jit(lambda p, c, t, pos: decode_step(p, cfg, c, t, pos),
                          in_shardings=in_sh)
-            with mesh_context(mesh):
+            with jax.set_mesh(mesh):
                 compiled = fn.lower(*args).compile()
         print("RESULT ok")
     """)

@@ -124,7 +124,9 @@ def test_quantized_pool_defs_and_store_dtype():
     blk = kv.pools[0][next(iter(kv.pools[0]))]
     assert blk["k"].dtype == jnp.int8 and blk["v"].dtype == jnp.int8
     assert blk["k_scale"].dtype == jnp.float32
-    assert blk["k_scale"].shape == blk["k"].shape[:-1]
+    # one (1, page) scale row per (page, kv_head): k (reps, P, KV, page, hd)
+    reps, P, KV, page, _ = blk["k"].shape
+    assert blk["k_scale"].shape == (reps, P, KV, 1, page)
 
 
 # -- kernel oracle identity ---------------------------------------------------
@@ -137,10 +139,11 @@ def test_gqa_decode_kernel_matches_quantized_oracle(kv_dtype, pipeline):
     P = 1 + B * nb
     ks = jax.random.split(jax.random.key(21), 3)
     q = jax.random.normal(ks[0], (B, KV, G, hd))
-    kq, k_s = kvq.quantize(jax.random.normal(ks[1], (P, page, KV, hd)),
+    kq, k_s = kvq.quantize(jax.random.normal(ks[1], (P, KV, page, hd)),
                            kv_dtype, -1)
-    vq, v_s = kvq.quantize(jax.random.normal(ks[2], (P, page, KV, hd)),
+    vq, v_s = kvq.quantize(jax.random.normal(ks[2], (P, KV, page, hd)),
                            kv_dtype, -1)
+    k_s, v_s = k_s[:, :, None], v_s[:, :, None]     # (P, KV, 1, page)
     bt, pos = _ragged_tables(np.random.RandomState(7), B, nb, page, P)
     ref = pa.paged_attention_reference(q, kq, vq, bt, pos, scale=hd ** -0.5,
                                        k_scale=k_s, v_scale=v_s)
@@ -157,10 +160,11 @@ def test_gqa_verify_kernel_matches_quantized_oracle(pipeline):
     P = 1 + B * nb
     ks = jax.random.split(jax.random.key(22), 3)
     q = jax.random.normal(ks[0], (B, T, KV, G, hd))
-    kq, k_s = kvq.quantize(jax.random.normal(ks[1], (P, page, KV, hd)),
+    kq, k_s = kvq.quantize(jax.random.normal(ks[1], (P, KV, page, hd)),
                            "int8", -1)
-    vq, v_s = kvq.quantize(jax.random.normal(ks[2], (P, page, KV, hd)),
+    vq, v_s = kvq.quantize(jax.random.normal(ks[2], (P, KV, page, hd)),
                            "int8", -1)
+    k_s, v_s = k_s[:, :, None], v_s[:, :, None]     # (P, KV, 1, page)
     bt, pos = _ragged_tables(np.random.RandomState(9), B, nb, page, P)
     pos = jnp.minimum(pos, nb * page - T)
     ref = pa.paged_attention_verify_reference(
@@ -185,6 +189,7 @@ def test_mla_decode_kernel_matches_quantized_oracle(kv_dtype, pipeline):
                            kv_dtype, -1)
     rq, r_s = kvq.quantize(jax.random.normal(ks[3], (P, page, dr)),
                            kv_dtype, -1)
+    c_s, r_s = c_s[:, None], r_s[:, None]           # (P, 1, page)
     bt, pos = _ragged_tables(np.random.RandomState(11), B, nb, page, P)
     ref = pa.mla_paged_attention_reference(
         ql, qr, cq, rq, bt, pos, scale=(r + dr) ** -0.5,
@@ -207,6 +212,7 @@ def test_mla_verify_kernel_matches_quantized_oracle(pipeline):
                            "int8", -1)
     rq, r_s = kvq.quantize(jax.random.normal(ks[3], (P, page, dr)),
                            "int8", -1)
+    c_s, r_s = c_s[:, None], r_s[:, None]           # (P, 1, page)
     bt, pos = _ragged_tables(np.random.RandomState(13), B, nb, page, P)
     pos = jnp.minimum(pos, nb * page - T)
     ref = pa.mla_paged_attention_verify_reference(

@@ -40,8 +40,8 @@ def test_gqa_kernel_matches_reference(B, KV, G, hd, page, nb):
     P = 1 + B * nb
     ks = jax.random.split(jax.random.key(B * 7 + nb), 3)
     q = jax.random.normal(ks[0], (B, KV, G, hd))
-    kp = jax.random.normal(ks[1], (P, page, KV, hd))
-    vp = jax.random.normal(ks[2], (P, page, KV, hd))
+    kp = jax.random.normal(ks[1], (P, KV, page, hd))
+    vp = jax.random.normal(ks[2], (P, KV, page, hd))
     bt, pos = _ragged_tables(np.random.RandomState(B), B, nb, page, P)
     scale = hd ** -0.5
     ref = pa.paged_attention_reference(q, kp, vp, bt, pos, scale=scale)
@@ -56,8 +56,8 @@ def test_gqa_kernel_soft_cap():
     P = 1 + B * nb
     ks = jax.random.split(jax.random.key(11), 3)
     q = jax.random.normal(ks[0], (B, KV, G, hd)) * 4.0
-    kp = jax.random.normal(ks[1], (P, page, KV, hd))
-    vp = jax.random.normal(ks[2], (P, page, KV, hd))
+    kp = jax.random.normal(ks[1], (P, KV, page, hd))
+    vp = jax.random.normal(ks[2], (P, KV, page, hd))
     bt, pos = _ragged_tables(np.random.RandomState(3), B, nb, page, P)
     ref = pa.paged_attention_reference(q, kp, vp, bt, pos, scale=0.25,
                                        soft_cap=30.0)
@@ -74,8 +74,8 @@ def test_gqa_kernel_idle_trash_lane_is_finite():
     P = 1 + B * nb
     ks = jax.random.split(jax.random.key(5), 3)
     q = jax.random.normal(ks[0], (B, KV, G, hd))
-    kp = jax.random.normal(ks[1], (P, page, KV, hd))
-    vp = jax.random.normal(ks[2], (P, page, KV, hd))
+    kp = jax.random.normal(ks[1], (P, KV, page, hd))
+    vp = jax.random.normal(ks[2], (P, KV, page, hd))
     bt = jnp.zeros((B, nb), jnp.int32)        # every lane idle -> trash page
     pos = jnp.zeros((B,), jnp.int32)
     out = pa.paged_attention(q, kp, vp, bt, pos, scale=hd ** -0.5,
@@ -119,8 +119,8 @@ def test_gqa_verify_kernel_matches_reference(B, T, KV, G, hd, page, nb):
     P = 1 + B * nb
     ks = jax.random.split(jax.random.key(B * 17 + T), 3)
     q = jax.random.normal(ks[0], (B, T, KV, G, hd))
-    kp = jax.random.normal(ks[1], (P, page, KV, hd))
-    vp = jax.random.normal(ks[2], (P, page, KV, hd))
+    kp = jax.random.normal(ks[1], (P, KV, page, hd))
+    vp = jax.random.normal(ks[2], (P, KV, page, hd))
     bt, pos = _ragged_tables(np.random.RandomState(B + T), B, nb, page, P)
     scale = hd ** -0.5
     ref = pa.paged_attention_verify_reference(q, kp, vp, bt, pos,
@@ -160,8 +160,8 @@ def test_verify_t1_matches_decode_reference():
     P = 1 + B * nb
     ks = jax.random.split(jax.random.key(23), 3)
     q = jax.random.normal(ks[0], (B, 1, KV, G, hd))
-    kp = jax.random.normal(ks[1], (P, page, KV, hd))
-    vp = jax.random.normal(ks[2], (P, page, KV, hd))
+    kp = jax.random.normal(ks[1], (P, KV, page, hd))
+    vp = jax.random.normal(ks[2], (P, KV, page, hd))
     bt, pos = _ragged_tables(np.random.RandomState(7), B, nb, page, P)
     dec = pa.paged_attention_reference(q[:, 0], kp, vp, bt, pos,
                                        scale=hd ** -0.5)
@@ -186,8 +186,8 @@ def test_gqa_pipeline_double_bitwise(B, KV, G, hd, page, nb):
     P = 1 + B * nb
     ks = jax.random.split(jax.random.key(B * 7 + nb), 3)
     q = jax.random.normal(ks[0], (B, KV, G, hd))
-    kp = jax.random.normal(ks[1], (P, page, KV, hd))
-    vp = jax.random.normal(ks[2], (P, page, KV, hd))
+    kp = jax.random.normal(ks[1], (P, KV, page, hd))
+    vp = jax.random.normal(ks[2], (P, KV, page, hd))
     bt, pos = _ragged_tables(np.random.RandomState(B), B, nb, page, P)
     kw = dict(scale=hd ** -0.5, soft_cap=25.0, interpret=True)
     off = pa.paged_attention(q, kp, vp, bt, pos, **kw)
@@ -230,8 +230,8 @@ def test_gqa_verify_pipeline_double_bitwise(B, T, KV, G, hd, page, nb):
     P = 1 + B * nb
     ks = jax.random.split(jax.random.key(B * 17 + T), 3)
     q = jax.random.normal(ks[0], (B, T, KV, G, hd))
-    kp = jax.random.normal(ks[1], (P, page, KV, hd))
-    vp = jax.random.normal(ks[2], (P, page, KV, hd))
+    kp = jax.random.normal(ks[1], (P, KV, page, hd))
+    vp = jax.random.normal(ks[2], (P, KV, page, hd))
     bt, pos = _ragged_tables(np.random.RandomState(B + T), B, nb, page, P)
     kw = dict(scale=hd ** -0.5, soft_cap=20.0, interpret=True)
     off = pa.paged_attention_verify(q, kp, vp, bt, pos, **kw)
@@ -277,8 +277,8 @@ def test_pipeline_double_idle_trash_lane_is_finite():
     P = 1 + B * nb
     ks = jax.random.split(jax.random.key(5), 3)
     q = jax.random.normal(ks[0], (B, KV, G, hd))
-    kp = jax.random.normal(ks[1], (P, page, KV, hd))
-    vp = jax.random.normal(ks[2], (P, page, KV, hd))
+    kp = jax.random.normal(ks[1], (P, KV, page, hd))
+    vp = jax.random.normal(ks[2], (P, KV, page, hd))
     bt = jnp.zeros((B, nb), jnp.int32)        # every lane idle -> trash page
     pos = jnp.zeros((B,), jnp.int32)
     off = pa.paged_attention(q, kp, vp, bt, pos, scale=hd ** -0.5,
@@ -293,8 +293,8 @@ def test_pipeline_rejects_unknown_mode():
     B, KV, G, hd, page, nb = 2, 2, 2, 16, 4, 3
     P = 1 + B * nb
     q = jnp.zeros((B, KV, G, hd))
-    kp = jnp.zeros((P, page, KV, hd))
-    vp = jnp.zeros((P, page, KV, hd))
+    kp = jnp.zeros((P, KV, page, hd))
+    vp = jnp.zeros((P, KV, page, hd))
     bt = jnp.zeros((B, nb), jnp.int32)
     pos = jnp.zeros((B,), jnp.int32)
     with pytest.raises(ValueError):
