@@ -1,0 +1,56 @@
+"""BENCHMARK.json names only what exists, and every cell finds its pieces
+by name."""
+
+import json
+import os
+import re
+import subprocess
+import sys
+
+import pytest
+
+from bench import harness
+
+SPEC = json.loads((harness.ROOT / "BENCHMARK.json").read_text())
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
+CELLS = [w["name"] for w in SPEC["workloads"]]
+
+
+def test_names_and_lengths():
+    for group in ("configs", "workloads", "end_to_end", "per_layer"):
+        names = [e["name"] for e in SPEC[group]]
+        assert len(names) == len(set(names))
+        assert all(NAME.match(n) for n in names)
+    for w in SPEC["workloads"]:
+        assert len(w["why"]) <= 200 and w["chips"] in (1, 4)
+    for m in SPEC["per_layer"]:
+        assert m["moves"] in {e["name"] for e in SPEC["end_to_end"]}
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_cell_finds_its_pieces(cell):
+    c = harness.load_cell(cell, SPEC)
+    names = [m["name"] for m in c.end_to_end]
+    assert "setup_s" in names and len(names) >= 2
+    assert c.per_layer
+    for m in c.end_to_end + c.per_layer:
+        if m["name"] != "setup_s":
+            assert callable(harness.metric_reader(m["name"]))
+    harness.model_modules(c.config)
+    assert c.chips == 1
+
+
+def test_reference_imports_nothing_of_the_program():
+    for path in (harness.BENCH / "models").glob("*_reference.py"):
+        assert "repro" not in path.read_text(), path
+
+
+def test_run_without_a_tpu_exits_nonzero_and_prints_no_result():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.run(
+        [sys.executable, str(harness.BENCH / "run.py"), "--workload",
+         CELLS[0], "--seed", "1", "--seconds", "1", "--trace", "0"],
+        cwd=harness.ROOT, env=env, capture_output=True, text=True,
+        timeout=120)
+    assert p.returncode != 0
+    assert p.stdout.strip() == ""
