@@ -374,6 +374,7 @@ def paged_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
         interpret=interpret,
+        name="paged_attention_decode",
     )(*args)
 
 
@@ -480,6 +481,7 @@ def mla_paged_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, r), q_lat.dtype),
         interpret=interpret,
+        name="mla_paged_attention_decode",
     )(*args)
 
 
@@ -584,6 +586,7 @@ def paged_attention_verify(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, T * G, hd), q.dtype),
         interpret=interpret,
+        name="paged_attention_verify",
     )(*args)
     return o.reshape(B, KV, T, G, hd).transpose(0, 2, 1, 3, 4)
 
@@ -686,6 +689,7 @@ def mla_paged_attention_verify(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, T * H, r), q_lat.dtype),
         interpret=interpret,
+        name="mla_paged_attention_verify",
     )(*args)
     return o.reshape(B, T, H, r)
 
@@ -827,6 +831,7 @@ def _gqa_paged_double(qf: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, rows, hd), qf.dtype),
         interpret=interpret,
+        name="paged_attention_double",
     )(*args)
 
 
@@ -956,6 +961,7 @@ def _mla_paged_double(qlf: jax.Array, qrf: jax.Array, c_pool: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, rows, r), qlf.dtype),
         interpret=interpret,
+        name="mla_paged_attention_double",
     )(*args)
 
 

@@ -3,7 +3,9 @@
 One :class:`Telemetry` bundle ties the three pieces together:
 
 * :class:`~repro.obs.trace.Tracer` — Chrome trace-event spans for every
-  lifecycle edge the stack already stamps (Perfetto/chrome://tracing),
+  lifecycle edge the stack already stamps (Perfetto/chrome://tracing);
+  :func:`~repro.obs.trace.span` writes each span to it and, as a
+  ``jax.profiler.TraceAnnotation``, to any running profiler trace,
 * :class:`~repro.obs.metrics.Registry` — counters/gauges/histograms
   projected from the ledgers/pool stats/latency traces the stack
   already keeps, with Prometheus text exposition,
@@ -28,10 +30,11 @@ from . import clock
 from .attainment import AttainmentTracker, AttainmentWindow
 from .metrics import Registry, harvest_serve
 from .trace import (ENGINE_TID, LIFECYCLE_TID, ROUTER_PID, SLOT_TID0,
-                    Tracer, validate_trace)
+                    Span, Tracer, span, validate_trace)
 
 __all__ = [
-    "Telemetry", "Tracer", "validate_trace", "Registry", "harvest_serve",
+    "Telemetry", "Tracer", "Span", "span", "validate_trace", "Registry",
+    "harvest_serve",
     "AttainmentTracker", "AttainmentWindow", "clock",
     "ENGINE_TID", "LIFECYCLE_TID", "SLOT_TID0", "ROUTER_PID",
 ]

@@ -1,13 +1,21 @@
 """Host-side span tracer with Chrome trace-event export.
 
 The serve stack already fences and stamps every interesting edge — the
-prefill chunk and decode windows bracket ``jax.block_until_ready`` with
+prefill and decode windows bracket ``jax.block_until_ready`` with
 monotonic stamps, preemption/swap/migration measure their DMAs, the
 router stamps submit and dispatch.  This tracer does nothing but record
 those existing stamps as structured events (a list append per edge; no
 device interaction, no extra fences), so tracing is observation-only by
 construction: token streams are byte-identical with it on or off, the
 same rule the roofline ledger obeys.
+
+Durations go through :func:`span`, which also opens a
+``jax.profiler.TraceAnnotation`` of the same name and stats: with the
+profiler running, every span lands in its trace on the host clock the
+device's operations share, so an idle stretch of the chip can be put
+down to the host work around it.  Span names are ``<layer>.<what>``
+(``sched.admit``, ``engine.prefill``, ``engine.decode.launch``,
+``kv.write_prefill``, ...).
 
 Export is the Chrome trace-event JSON format, loadable in
 ``chrome://tracing`` or https://ui.perfetto.dev: one *process* per
@@ -21,9 +29,10 @@ counters charted above them.
 Event vocabulary (kept deliberately small so the validator can be
 strict):
 
-* ``X`` duration slices for serially-executed device windows only —
-  prefill chunks, decode/verify/propose steps, swap/migrate DMAs.  On
-  one track these never partially overlap (they may nest), which
+* ``X`` duration slices for the serial host work of a step and the
+  device windows inside it — admission, prefill and its page writes,
+  decode staging/launch/fence/commit, verify/propose steps, swap/migrate
+  DMAs.  On one track these never partially overlap (they nest), which
   :func:`validate_trace` enforces.
 * ``b``/``e`` async pairs (per request id) for request lifetimes —
   allowed to overlap arbitrarily.
@@ -41,11 +50,13 @@ import json
 import math
 from typing import Any, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 from . import clock
 
 # Track (tid) layout inside each replica process.  Slot tracks start at
 # SLOT_TID0 so engine/lifecycle tracks sort above them in the viewer.
-ENGINE_TID = 0          # packed device steps: decode/verify/propose
+ENGINE_TID = 0          # admission and the packed decode/verify step
 LIFECYCLE_TID = 1       # request instants + async request spans
 SLOT_TID0 = 10          # per-slot prefill/swap/migrate spans
 ROUTER_PID = 999        # the front door is its own process
@@ -147,6 +158,49 @@ class Tracer:
             with open(path, "w") as f:
                 json.dump(doc, f)
         return doc
+
+
+class Span:
+    """One host span, written to two traces under one name and one set of
+    stats: always a ``jax.profiler.TraceAnnotation`` (so it lands in any
+    profiler trace on the host clock the device's ``XLA Ops`` share), and
+    a Chrome ``X`` event when a :class:`~repro.obs.Telemetry` bundle is
+    attached.  ``t0``/``t1`` are the :func:`clock.now` stamps at entry and
+    exit, for callers whose phase walls must be the span's own interval.
+    ``set`` adds stats known only inside the span; ``tid`` may be set
+    before exit when the Chrome track is known only then."""
+
+    __slots__ = ("obs", "name", "pid", "tid", "stats", "t0", "t1", "_ann")
+
+    def __init__(self, obs, name: str, pid: int, tid: int, stats):
+        self.obs, self.name, self.pid, self.tid = obs, name, pid, tid
+        self.stats = stats
+        self.t0 = self.t1 = 0.0
+
+    def set(self, **stats) -> None:
+        self.stats.update(stats)
+        self._ann.set_metadata(**stats)
+
+    def __enter__(self) -> "Span":
+        self._ann = TraceAnnotation(self.name, **self.stats)
+        self._ann.__enter__()
+        self.t0 = clock.now()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.t1 = clock.now()
+        self._ann.__exit__(*exc)
+        if self.obs is not None:
+            self.obs.tracer.span(self.name, self.pid, self.tid, self.t0,
+                                 self.t1, **self.stats)
+
+
+def span(obs, name: str, pid: int = 0, tid: int = ENGINE_TID,
+         **stats) -> Span:
+    """``with span(engine.obs, "engine.decode", pid, batch=n):`` — see
+    :class:`Span`.  Costs about a microsecond with no profiler running
+    and ``obs`` None.  Names are ``<layer>.<what>``."""
+    return Span(obs, name, pid, tid, stats)
 
 
 _REQUIRED = ("ph", "name", "pid", "tid")

@@ -44,7 +44,7 @@ from repro.models import (decode_step, decode_step_paged, init_cache,
 from repro.models.common import ModelConfig, model_flops
 from repro.obs import Telemetry
 from repro.obs.clock import now
-from repro.obs.trace import ENGINE_TID, LIFECYCLE_TID, SLOT_TID0
+from repro.obs.trace import ENGINE_TID, LIFECYCLE_TID, SLOT_TID0, span
 
 from . import sampling
 from .kv_cache import PagedKVCache, supports_paging
@@ -287,18 +287,25 @@ class Engine:
         cfg, ps = self.cfg, e.page_size
 
         self._decode_fn = jax.jit(self._decode_callable(cfg))
+
+        # named functions, so each program keeps a stable name
+        # (jit__prefill_chunk, jit__prefill_bucket) in a profiler trace
+        def _prefill_chunk(p, pools, btr, slot, toks, off):
+            return prefill_chunk_paged(p, cfg, pools, btr, slot, toks, off,
+                                       page_size=ps)
+
+        def _prefill_bucket(p, toks, n):
+            return prefill_padded(p, cfg, toks, n)
+
         # jit handles per-chunk-length retracing under one cache
-        self._prefill_fn = jax.jit(
-            lambda p, pools, btr, slot, toks, off: prefill_chunk_paged(
-                p, cfg, pools, btr, slot, toks, off, page_size=ps))
+        self._prefill_fn = jax.jit(_prefill_chunk)
         # bucketed whole-prompt prefill: only archs whose collected states
         # are all per-token (attention/MLA) survive padding — a recurrent
         # final state or an MoE capacity cutoff would see the pad tokens
         self._bucketable = (
             all(b.mixer in ("attn", "mla") for b in cfg.block_pattern)
             and all(b.ffn != "moe" for b in cfg.block_pattern))
-        self._prefill_full_fn = jax.jit(
-            lambda p, toks, n: prefill_padded(p, cfg, toks, n))
+        self._prefill_full_fn = jax.jit(_prefill_bucket)
         self.prefill_shapes: set = set()
         self.step_count = 0
         self.decode_steps = 0
@@ -403,20 +410,31 @@ class Engine:
     def step(self) -> List[Request]:
         """One scheduler iteration: admit (resuming preempted requests
         first), prefill one chunk per admitted request, one packed decode
-        step.  Returns requests finished here."""
-        sched = self._sched
+        step.  Returns requests finished here.
+
+        Each part runs inside a :func:`repro.obs.trace.span` (``sched.admit``,
+        ``engine.prefill``, ``engine.decode`` and the spans nested in them),
+        so a profiler trace names the host work behind every idle stretch
+        of the chip."""
+        sched, obs, pid = self._sched, self.obs, self._obs_pid
         n_done = len(sched.finished)
-        admitted = sched.admit()
-        for req in admitted:
-            self._init_sampling_row(req)
-            if req.state is RequestState.RUNNING:
-                self._restore_decode_row(req)        # swap-resume
+        with span(obs, "sched.admit", pid) as sp:
+            admitted = sched.admit()
+            for req in admitted:
+                self._init_sampling_row(req)
+                if req.state is RequestState.RUNNING:
+                    self._restore_decode_row(req)        # swap-resume
+            sp.set(admitted=len(admitted), waiting=len(sched.waiting))
         work = sched.prefill_work()
         for req, start, end in work:
-            self._run_prefill(req, start, end)
+            with span(obs, "engine.prefill", pid, SLOT_TID0 + req.slot,
+                      request=req.request_id,
+                      prompt_len=len(req.fill_tokens), start=start, end=end):
+                self._run_prefill(req, start, end)
         running = sched.decode_requests()
         if running:
-            self._run_decode(running)
+            with span(obs, "engine.decode", pid) as sp:
+                sp.set(batch=self._run_decode(running))
         elif (not admitted and not work
                 and (sched.waiting or sched.preempted)):
             head = (sched.preempted + list(sched.waiting))[0]
@@ -562,53 +580,58 @@ class Engine:
 
     def _run_prefill(self, req: Request, start: int, end: int) -> None:
         kv, cfg = self._kv, self.cfg
+        obs, pid, tid = self.obs, self._obs_pid, SLOT_TID0 + req.slot
         fill = req.fill_tokens
         fill_len = len(fill)
         # chunk writes can hit a prefix-shared page (copy-on-write needs a
         # fresh page) — back the span first, preempting if the pool is dry
-        if not self._grow_spans([req], lambda r: (start, end)):
-            return                          # req itself was preempted
+        with span(obs, "kv.grow", pid, tid):
+            if not self._grow_spans([req], lambda r: (start, end)):
+                return                      # req itself was preempted
         whole = start == 0 and end == fill_len
-        t0 = now()
-        if whole and self._bucketable and self.ecfg.prefill_bucket > 0:
-            # length-bucketed jitted prefill: pad the prompt to the next
-            # power of two; causal masking makes the prefix rows (and the
-            # logits at true_len-1) byte-identical to the unpadded run, so
-            # at most O(log max_len) shapes ever compile
-            pl_ = _bucket_len(fill_len, self.ecfg.prefill_bucket)
-            toks = np.zeros((1, pl_), np.int32)
-            toks[0, :fill_len] = fill
-            self.prefill_shapes.add(pl_)
-            last_logits, states = self._prefill_full_fn(
-                self.params, jnp.asarray(toks), jnp.int32(fill_len))
-            kv.write_prefill_states(req.slot, states, fill_len)
-        elif whole:
-            # one-chunk path: identical computation to the static engine
-            last_logits, states = prefill(self.params, cfg,
-                                          jnp.asarray(fill[None, :]))
-            kv.write_prefill_states(req.slot, states, fill_len)
-        else:
-            btr = jnp.asarray(kv.block_tables[req.slot])
-            toks = jnp.asarray(fill[None, start:end])
-            last_logits, kv.pools = self._prefill_fn(
-                self.params, kv.pools, btr, jnp.int32(req.slot), toks,
-                jnp.int32(start))
-            if kv.prefix_cache:
-                # chunked-prefill-safe eager registration: every full page
-                # this chunk finalized holds canonical prompt content NOW,
-                # so it is index-shareable steps before the request commits
-                # its first token (alloc-time registration stays gated to
-                # whole-prompt prefill — those pages are only promised, not
-                # yet written)
-                kv.freeze_committed(req.slot, fill, end)
+        # length-bucketed jitted prefill: pad the prompt to the next power
+        # of two; causal masking makes the prefix rows (and the logits at
+        # true_len-1) byte-identical to the unpadded run, so at most
+        # O(log max_len) shapes ever compile
+        bucketed = whole and self._bucketable and self.ecfg.prefill_bucket > 0
+        bucket = (_bucket_len(fill_len, self.ecfg.prefill_bucket)
+                  if bucketed else end - start)
+        # the phase wall runs from this launch to the fence below
+        with span(obs, "engine.prefill.launch", pid, tid,
+                  bucket=bucket) as launch:
+            if bucketed:
+                toks = np.zeros((1, bucket), np.int32)
+                toks[0, :fill_len] = fill
+                self.prefill_shapes.add(bucket)
+                last_logits, states = self._prefill_full_fn(
+                    self.params, jnp.asarray(toks), jnp.int32(fill_len))
+            elif whole:
+                # one-chunk path: identical computation to the static engine
+                last_logits, states = prefill(self.params, cfg,
+                                              jnp.asarray(fill[None, :]))
+            else:
+                btr = jnp.asarray(kv.block_tables[req.slot])
+                toks = jnp.asarray(fill[None, start:end])
+                last_logits, kv.pools = self._prefill_fn(
+                    self.params, kv.pools, btr, jnp.int32(req.slot), toks,
+                    jnp.int32(start))
+        if whole:
+            # eager page writes: dispatched here, run on the device after
+            # the logits, so the first-token sample waits behind them
+            with span(obs, "kv.write_prefill", pid, tid):
+                kv.write_prefill_states(req.slot, states, fill_len)
+        elif kv.prefix_cache:
+            # chunked-prefill-safe eager registration: every full page
+            # this chunk finalized holds canonical prompt content NOW, so
+            # it is index-shareable steps before the request commits its
+            # first token (alloc-time registration stays gated to
+            # whole-prompt prefill — those pages are only promised, not
+            # yet written)
+            kv.freeze_committed(req.slot, fill, end)
         # fence before stamping (async dispatch; see _run_decode)
-        jax.block_until_ready(last_logits)
-        t1 = now()
-        if self.obs is not None:
-            self.obs.tracer.span("prefill_chunk", self._obs_pid,
-                                 SLOT_TID0 + req.slot, t0, t1,
-                                 request=req.request_id, start=start,
-                                 end=end)
+        with span(obs, "engine.prefill.fence", pid, tid) as fence:
+            jax.block_until_ready(last_logits)
+        t0, t1 = launch.t0, fence.t1
         n_new = end - start
         self._sched.phases["prefill"].add(
             flops=(model_flops(cfg, end, 1, "prefill")
@@ -638,18 +661,19 @@ class Engine:
                 # prefill-only scoring: same shape contract as StaticEngine
                 self._sched.finish(req, "length")
                 return
-            tok = self._sample_first(last_logits, req)
-            self._commit_token(req, tok, first=True)
+            with span(obs, "engine.sample_first", pid, tid):
+                tok = self._sample_first(last_logits, req)
+                self._commit_token(req, tok, first=True)
 
-    def _grow_spans(self, reqs: List[Request], span) -> List[Request]:
-        """Back every request's write span ``span(req) -> (start, end)``
+    def _grow_spans(self, reqs: List[Request], extent) -> List[Request]:
+        """Back every request's write span ``extent(req) -> (start, end)``
         before a device step runs: on-demand page growth plus copy-on-write
         privatization.  When the pool runs dry (even after evicting cached
         pages) the newest-admitted RUNNING request is preempted and the
         growth retried; requests that got preempted (possibly the one being
         grown) drop out of the returned list."""
         for req in sorted(reqs, key=lambda r: r.admit_seq):
-            s, e = span(req)
+            s, e = extent(req)
             while (req.state is not RequestState.PREEMPTED
                    and not self._kv.ensure_writable(req.slot, s, e)):
                 victim = self._sched.preempt_victim()
@@ -676,59 +700,70 @@ class Engine:
         self._pos[req.slot] = req.context_len - 1
         self._steps[req.slot] = len(req.generated)
 
-    def _run_decode(self, running: List[Request]) -> None:
-        kv = self._kv
-        # the step writes each request's newest KV line at context_len - 1:
-        # back that position (page growth / copy-on-write) before launching
-        running = self._grow_spans(
-            running, lambda r: (r.context_len - 1, r.context_len))
+    def _grow_decode(self, running: List[Request], n_tokens: int):
+        """Back the ``n_tokens`` KV lines one packed step writes from each
+        request's ``context_len - 1`` (page growth / copy-on-write; a dry
+        pool preempts) inside a ``kv.grow`` span.  Returns the requests
+        still running and their block tables (None when none is)."""
+        with span(self.obs, "kv.grow", self._obs_pid):
+            running = self._grow_spans(
+                running,
+                lambda r: (r.context_len - 1, r.context_len - 1 + n_tokens))
+            if not running:
+                return running, None
+            return running, self._kv.block_tables_for(
+                [r.slot for r in running])
+
+    def _run_decode(self, running: List[Request]) -> int:
+        """One packed decode step; returns how many requests it stepped."""
+        kv, obs, pid = self._kv, self.obs, self._obs_pid
+        running, bt = self._grow_decode(running, 1)
         if not running:
-            return
-        slots = [r.slot for r in running]
-        bt = kv.block_tables_for(slots)
-        active = np.zeros((self.ecfg.num_slots,), bool)
-        active[slots] = True
-        token = np.where(active, self._next_token, 0).astype(np.int32)
-        pos = np.where(active, self._pos, 0).astype(np.int32)
+            return 0
         # decode + batched sampling run as ONE jitted step: the host sees
         # only the chosen token ids, never the (B, V) logits.  Argument
         # conversion happens BEFORE the fenced window so the phase wall
         # measures the device step, not host-side staging
-        step_args = (self.params, kv.pools, bt, jnp.asarray(token[:, None]),
-                     jnp.asarray(pos), jnp.asarray(active),
-                     jnp.asarray(self._key_data), jnp.asarray(self._steps),
-                     jnp.asarray(self._temps), jnp.asarray(self._top_ks),
-                     jnp.asarray(self._top_ps))
-        t0 = now()
-        next_tok, kv.pools = self._decode_fn(*step_args)
+        with span(obs, "engine.decode.stage", pid):
+            active = np.zeros((self.ecfg.num_slots,), bool)
+            active[[r.slot for r in running]] = True
+            token = np.where(active, self._next_token, 0).astype(np.int32)
+            pos = np.where(active, self._pos, 0).astype(np.int32)
+            step_args = (self.params, kv.pools, bt,
+                         jnp.asarray(token[:, None]), jnp.asarray(pos),
+                         jnp.asarray(active), jnp.asarray(self._key_data),
+                         jnp.asarray(self._steps), jnp.asarray(self._temps),
+                         jnp.asarray(self._top_ks), jnp.asarray(self._top_ps))
+        with span(obs, "engine.decode.launch", pid) as launch:
+            next_tok, kv.pools = self._decode_fn(*step_args)
         # fence BEFORE stamping: dispatch is async, so an unfenced stamp
         # records launch time, not completion — every request committed
         # this step shares one post-fence stamp
-        jax.block_until_ready(next_tok)
-        t1 = now()
+        with span(obs, "engine.decode.fence", pid) as fence:
+            jax.block_until_ready(next_tok)
+        t0, t1 = launch.t0, fence.t1
         self.decode_steps += 1
-        if self.obs is not None:
-            self.obs.tracer.span("decode_step", self._obs_pid, ENGINE_TID,
-                                 t0, t1, batch=len(running))
-        tok_np = np.asarray(next_tok)
-        n_active = len(running)
-        ici_share = self._step_collective_bytes(1) / n_active
-        ph = self._sched.phases["decode"]
-        ps = self.ecfg.page_size
-        for req in running:
-            vmem = decode_token_vmem_bytes(self.cfg, req.context_len,
-                                           n_active, ps,
-                                           pipeline=self.ecfg.pipeline)
-            req.ledger.add_decode_token(self.cfg, req.context_len, n_active,
-                                        ici_bytes=ici_share,
-                                        vmem_bytes=vmem)
-            ph.add(flops=decode_token_flops(self.cfg, req.context_len),
-                   vmem=vmem,
-                   hbm=decode_token_bytes(self.cfg, req.context_len,
-                                          n_active),
-                   ici=ici_share, steps=0, tokens=1)
-            self._commit_token(req, int(tok_np[req.slot]), t=t1)
-        ph.add(wall_s=t1 - t0, steps=1, tokens=0)
+        with span(obs, "engine.decode.commit", pid):
+            tok_np = np.asarray(next_tok)
+            n_active = len(running)
+            ici_share = self._step_collective_bytes(1) / n_active
+            ph = self._sched.phases["decode"]
+            ps = self.ecfg.page_size
+            for req in running:
+                vmem = decode_token_vmem_bytes(self.cfg, req.context_len,
+                                               n_active, ps,
+                                               pipeline=self.ecfg.pipeline)
+                req.ledger.add_decode_token(self.cfg, req.context_len,
+                                            n_active, ici_bytes=ici_share,
+                                            vmem_bytes=vmem)
+                ph.add(flops=decode_token_flops(self.cfg, req.context_len),
+                       vmem=vmem,
+                       hbm=decode_token_bytes(self.cfg, req.context_len,
+                                              n_active),
+                       ici=ici_share, steps=0, tokens=1)
+                self._commit_token(req, int(tok_np[req.slot]), t=t1)
+            ph.add(wall_s=t1 - t0, steps=1, tokens=0)
+        return n_active
 
     def _commit_token(self, req: Request, tok: int, first: bool = False,
                       t: Optional[float] = None) -> None:

@@ -73,7 +73,7 @@ from repro.kernels.paged_attention import (mla_paged_decode_vmem_bytes,
                                            paged_decode_vmem_bytes)
 from repro.models.common import ModelConfig, model_flops, param_counts
 from repro.obs.clock import now
-from repro.obs.trace import LIFECYCLE_TID, SLOT_TID0
+from repro.obs.trace import LIFECYCLE_TID, SLOT_TID0, span
 
 from .kv_cache import PagedKVCache
 
@@ -695,36 +695,28 @@ class Scheduler:
                     or self.kv.swap_in_pages_needed(snap)
                     > self.kv.available_page_count):
                 return False
-            t0 = now()
-            slot = self.kv.swap_in(snap)
-            if slot is None:
-                return False
-            jax.block_until_ready(self.kv.pools)
-            t1 = now()
+            # restore leg of a cross-replica migration: the wire bytes
+            # were charged at detach; the restore DMA is host traffic on
+            # THIS replica, phase "migrate" not "swap"
+            phase = "migrate" if req.migrating else "swap"
+            with span(self.obs, phase + "_in", self.obs_pid,
+                      request=req.request_id,
+                      bytes=int(snap.nbytes)) as sp:
+                slot = self.kv.swap_in(snap)
+                if slot is None:
+                    return False
+                sp.tid = SLOT_TID0 + slot
+                jax.block_until_ready(self.kv.pools)
+            self.phases[phase].add(host=float(snap.nbytes),
+                                   wall_s=sp.t1 - sp.t0)
             if req.migrating:
-                # restore leg of a cross-replica migration: the wire
-                # bytes were charged at detach; the restore DMA is host
-                # traffic on THIS replica, phase "migrate" not "swap"
-                self.phases["migrate"].add(host=float(snap.nbytes),
-                                           wall_s=t1 - t0)
                 req.migrating = False
                 if self.obs is not None:
-                    self.obs.tracer.span(
-                        "migrate_in", self.obs_pid, SLOT_TID0 + slot,
-                        t0, t1, request=req.request_id,
-                        bytes=int(snap.nbytes))
                     self.obs.tracer.flow_finish(
                         "migrate", self.obs_pid, SLOT_TID0 + slot,
-                        req.request_id, t1)
+                        req.request_id, sp.t1)
             else:
-                self.phases["swap"].add(host=float(snap.nbytes),
-                                        wall_s=t1 - t0)
                 req.ledger.swap_bytes += snap.nbytes
-                if self.obs is not None:
-                    self.obs.tracer.span(
-                        "swap_in", self.obs_pid, SLOT_TID0 + slot,
-                        t0, t1, request=req.request_id,
-                        bytes=int(snap.nbytes))
             req.swap_snapshot = None
             self._place(req, slot, prefilling=False)
             return True
@@ -768,18 +760,14 @@ class Scheduler:
         assert req.state in (RequestState.PREFILL, RequestState.RUNNING)
         del self.active[req.slot]
         if self.preempt_mode == "swap" and req.state is RequestState.RUNNING:
-            t0 = now()
-            snap = self.kv.swap_out(req.slot)
-            t1 = now()
+            with span(self.obs, "swap_out", self.obs_pid,
+                      SLOT_TID0 + req.slot, request=req.request_id) as sp:
+                snap = self.kv.swap_out(req.slot)
+                sp.set(bytes=int(snap.nbytes))
             self.phases["swap"].add(host=float(snap.nbytes),
-                                    wall_s=t1 - t0)
+                                    wall_s=sp.t1 - sp.t0)
             req.swap_snapshot = snap
             req.ledger.swap_bytes += snap.nbytes
-            if self.obs is not None:
-                self.obs.tracer.span(
-                    "swap_out", self.obs_pid, SLOT_TID0 + req.slot,
-                    t0, t1, request=req.request_id,
-                    bytes=int(snap.nbytes))
         else:
             # recompute (or mid-prefill eviction): snapshot the committed
             # context; resume re-prefills it from scratch
@@ -811,14 +799,11 @@ class Scheduler:
             req.state)
         if req.state is RequestState.RUNNING:
             del self.active[req.slot]
-            t0 = now()
-            snap = self.kv.swap_out(req.slot)
-            wall = now() - t0
-            if self.obs is not None:
-                self.obs.tracer.span(
-                    "migrate_out", self.obs_pid, SLOT_TID0 + req.slot,
-                    t0, t0 + wall, request=req.request_id,
-                    bytes=int(snap.nbytes))
+            with span(self.obs, "migrate_out", self.obs_pid,
+                      SLOT_TID0 + req.slot, request=req.request_id) as sp:
+                snap = self.kv.swap_out(req.slot)
+                sp.set(bytes=int(snap.nbytes))
+            wall = sp.t1 - sp.t0
             req.swap_snapshot = snap
             req.slot = -1
             req.state = RequestState.PREEMPTED

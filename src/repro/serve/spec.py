@@ -40,8 +40,7 @@ import numpy as np
 
 from repro.models import decode_step_verify_paged
 from repro.models.common import ModelConfig
-from repro.obs.clock import now
-from repro.obs.trace import ENGINE_TID
+from repro.obs.trace import span
 
 from . import sampling
 from .engine import Engine, EngineConfig
@@ -261,104 +260,100 @@ class SpecEngine(Engine):
 
     # -- decode = propose -> verify -> accept -> commit --------------------
 
-    def _run_decode(self, running: List[Request]) -> None:
-        kv, s = self._kv, self.scfg
+    def _run_decode(self, running: List[Request]) -> int:
+        kv, s, pid = self._kv, self.scfg, self._obs_pid
         k, T = s.k, s.k + 1
         # the verify step writes T KV lines from context_len - 1 on:
         # back the whole span (growth + copy-on-write) so speculative
         # scribbles can never land on a shared page; past-budget overflow
         # is clipped onto the trash-margin entries as before
-        running = self._grow_spans(
-            running, lambda r: (r.context_len - 1, r.context_len - 1 + T))
+        running, bt = self._grow_decode(running, T)
         if not running:
-            return
-        slots = [r.slot for r in running]
-        bt = kv.block_tables_for(slots)
+            return 0
         active = np.zeros((self.ecfg.num_slots,), bool)
-        active[slots] = True
+        active[[r.slot for r in running]] = True
         k_eff = None
         if s.adaptive:
             k_eff = np.full((self.ecfg.num_slots,), k, np.int32)
             for req in running:
                 a = self._accept_ewma.get(req.request_id, 1.0)
                 k_eff[req.slot] = adaptive_k(a, k, s.adapt_floor, s.k_min)
-        td0 = now()
-        prop = self.proposer.propose(running, k_eff=k_eff)
-        td1 = now()
-        self._sched.phases["draft"].add(wall_s=td1 - td0, steps=1)
-        if self.obs is not None:
-            self.obs.tracer.span("propose", self._obs_pid, ENGINE_TID,
-                                 td0, td1, batch=len(running))
+        with span(self.obs, "engine.propose", pid,
+                  batch=len(running)) as draft:
+            prop = self.proposer.propose(running, k_eff=k_eff)
+        self._sched.phases["draft"].add(wall_s=draft.t1 - draft.t0, steps=1)
 
-        feed = np.zeros((self.ecfg.num_slots, T), np.int32)
-        feed[:, 0] = np.where(active, self._next_token, 0)
-        feed[:, 1:] = prop.draft
-        pos = np.where(active, self._pos, 0).astype(np.int32)
-        args = [self.params, kv.pools, bt, jnp.asarray(feed),
-                jnp.asarray(pos), jnp.asarray(active),
-                jnp.asarray(prop.draft)]
-        if prop.q_probs is not None:
-            args.append(prop.q_probs)
-        args += [jnp.asarray(prop.n_draft), jnp.asarray(self._key_data),
-                 jnp.asarray(self._steps), jnp.asarray(self._temps),
-                 jnp.asarray(self._top_ks), jnp.asarray(self._top_ps)]
-        # args are converted above, outside the fenced window (the phase
+        # args are converted here, outside the fenced window (the phase
         # wall measures the device step, not host-side staging)
-        t0 = now()
-        out_tok, n_out, kv.pools = self._verify_fn(*args)
-        # fence before stamping (async dispatch; see Engine._run_decode)
-        jax.block_until_ready(out_tok)
-        t1 = now()
+        with span(self.obs, "engine.decode.stage", pid):
+            feed = np.zeros((self.ecfg.num_slots, T), np.int32)
+            feed[:, 0] = np.where(active, self._next_token, 0)
+            feed[:, 1:] = prop.draft
+            pos = np.where(active, self._pos, 0).astype(np.int32)
+            args = [self.params, kv.pools, bt, jnp.asarray(feed),
+                    jnp.asarray(pos), jnp.asarray(active),
+                    jnp.asarray(prop.draft)]
+            if prop.q_probs is not None:
+                args.append(prop.q_probs)
+            args += [jnp.asarray(prop.n_draft), jnp.asarray(self._key_data),
+                     jnp.asarray(self._steps), jnp.asarray(self._temps),
+                     jnp.asarray(self._top_ks), jnp.asarray(self._top_ps)]
+        with span(self.obs, "engine.verify", pid, batch=len(running),
+                  k=k) as verify:
+            out_tok, n_out, kv.pools = self._verify_fn(*args)
+            # fence before stamping (async dispatch; see Engine._run_decode)
+            jax.block_until_ready(out_tok)
+        t0, t1 = verify.t0, verify.t1
         self.decode_steps += 1
         self.verify_steps += 1
-        if self.obs is not None:
-            self.obs.tracer.span("verify", self._obs_pid, ENGINE_TID,
-                                 t0, t1, batch=len(running), k=k)
-
-        out_np = np.asarray(out_tok)
-        n_np = np.asarray(n_out)
-        n_active = len(running)
-        ici_share = self._step_collective_bytes(T) / n_active
-        vph = self._sched.phases["verify"]
-        ps = self.ecfg.page_size
-        line = kv_line_bytes(self.cfg)
-        for req in running:
-            slot, L = req.slot, req.context_len
-            nd = int(prop.n_draft[slot])
-            n = max(1, min(int(n_np[slot]), nd + 1))
-            committed = 0
-            for j in range(n):
-                self._commit_token(req, int(out_np[slot, j]), t=t1)
-                committed += 1
-                if req.state is RequestState.FINISHED:
-                    break
-            # the last committed token is the corrected/bonus draw only if
-            # the commit chain ran to completion; a stop-token or budget
-            # cut means everything committed was an accepted draft
-            accepted = committed - 1 if committed == n else committed
-            vmem = verify_step_vmem_bytes(self.cfg, L, T, n_active, ps,
-                                          pipeline=self.ecfg.pipeline)
-            req.ledger.add_verify_step(self.cfg, L, T, committed, accepted,
-                                       nd, n_active, ici_bytes=ici_share,
-                                       vmem_bytes=vmem)
-            vph.add(flops=sum(decode_token_flops(self.cfg, L + t)
-                              for t in range(T)),
-                    vmem=vmem,
-                    hbm=(params_bytes_active(self.cfg) / n_active
-                         + (L + 2 * T - 1) * line
-                         + 2 * state_bytes(self.cfg)),
-                    ici=ici_share, steps=0, tokens=committed)
-            if s.adaptive and nd > 0:
-                prev = self._accept_ewma.get(req.request_id, 1.0)
-                obs = accepted / nd
-                self._accept_ewma[req.request_id] = (
-                    (1.0 - s.ewma_beta) * prev + s.ewma_beta * obs)
-            if s.proposer == "draft":
-                n_fed = int(prop.n_catchup[slot])
-                n_decodes = max(int(prop.n_draft[slot]) - 1, 0)
-                req.ledger.add_draft_cost(s.draft_cfg, L, n_fed, n_decodes,
-                                          n_active)
-        vph.add(wall_s=t1 - t0, steps=1, tokens=0)
+        with span(self.obs, "engine.decode.commit", pid):
+            out_np = np.asarray(out_tok)
+            n_np = np.asarray(n_out)
+            n_active = len(running)
+            ici_share = self._step_collective_bytes(T) / n_active
+            vph = self._sched.phases["verify"]
+            ps = self.ecfg.page_size
+            line = kv_line_bytes(self.cfg)
+            for req in running:
+                slot, L = req.slot, req.context_len
+                nd = int(prop.n_draft[slot])
+                n = max(1, min(int(n_np[slot]), nd + 1))
+                committed = 0
+                for j in range(n):
+                    self._commit_token(req, int(out_np[slot, j]), t=t1)
+                    committed += 1
+                    if req.state is RequestState.FINISHED:
+                        break
+                # the last committed token is the corrected/bonus draw only
+                # if the commit chain ran to completion; a stop-token or
+                # budget cut means everything committed was an accepted
+                # draft
+                accepted = committed - 1 if committed == n else committed
+                vmem = verify_step_vmem_bytes(self.cfg, L, T, n_active, ps,
+                                              pipeline=self.ecfg.pipeline)
+                req.ledger.add_verify_step(self.cfg, L, T, committed,
+                                           accepted, nd, n_active,
+                                           ici_bytes=ici_share,
+                                           vmem_bytes=vmem)
+                vph.add(flops=sum(decode_token_flops(self.cfg, L + t)
+                                  for t in range(T)),
+                        vmem=vmem,
+                        hbm=(params_bytes_active(self.cfg) / n_active
+                             + (L + 2 * T - 1) * line
+                             + 2 * state_bytes(self.cfg)),
+                        ici=ici_share, steps=0, tokens=committed)
+                if s.adaptive and nd > 0:
+                    prev = self._accept_ewma.get(req.request_id, 1.0)
+                    obs = accepted / nd
+                    self._accept_ewma[req.request_id] = (
+                        (1.0 - s.ewma_beta) * prev + s.ewma_beta * obs)
+                if s.proposer == "draft":
+                    n_fed = int(prop.n_catchup[slot])
+                    n_decodes = max(int(prop.n_draft[slot]) - 1, 0)
+                    req.ledger.add_draft_cost(s.draft_cfg, L, n_fed,
+                                              n_decodes, n_active)
+            vph.add(wall_s=t1 - t0, steps=1, tokens=0)
+        return len(running)
 
     def _preempt(self, req: Request) -> None:
         # the draft proposer's mirrored slot must go with the target's —

@@ -25,8 +25,8 @@ from repro.configs import get_config, smoke
 from repro.models import init_params
 from repro.obs import Telemetry, clock
 from repro.obs.metrics import Counter, Registry, harvest_serve
-from repro.obs.trace import (ENGINE_TID, LIFECYCLE_TID, Tracer,
-                             validate_trace)
+from repro.obs.trace import (ENGINE_TID, LIFECYCLE_TID, SLOT_TID0, Tracer,
+                             span, validate_trace)
 from repro.serve import (Cluster, Engine, EngineConfig, GenerateConfig,
                          RoleConfig, Router, SpecConfig, SpecEngine)
 
@@ -203,7 +203,7 @@ def test_engine_byte_identity_telemetry_on_off():
     doc = eng.obs.export_trace()
     assert validate_trace(doc) == []
     names = {e["name"] for e in doc["traceEvents"]}
-    assert {"prefill_chunk", "decode_step", "submit", "place",
+    assert {"engine.prefill", "engine.decode", "submit", "place",
             "first_token", "request"} <= names
 
 
@@ -214,7 +214,7 @@ def test_spec_engine_byte_identity_and_spans():
     doc = eng.obs.export_trace()
     assert validate_trace(doc) == []
     names = {e["name"] for e in doc["traceEvents"]}
-    assert {"propose", "verify"} <= names
+    assert {"engine.propose", "engine.verify"} <= names
 
 
 def test_router_byte_identity_and_migration_trace():
@@ -243,7 +243,7 @@ def test_router_byte_identity_and_migration_trace():
     assert validate_trace(doc) == []
     names = {e["name"] for e in doc["traceEvents"]}
     assert {"dispatch", "migrate", "migrate_out", "migrate_in",
-            "prefill_chunk", "decode_step"} <= names
+            "engine.prefill", "engine.decode"} <= names
     # every migration draws one complete flow arrow between replicas
     flows = [e for e in doc["traceEvents"] if e["ph"] in ("s", "f")]
     starts = {e["id"] for e in flows if e["ph"] == "s"}
@@ -255,6 +255,142 @@ def test_router_byte_identity_and_migration_trace():
     snap = obs.snapshot()
     assert "serve_migrations_total" in snap
     assert 'serve_migration_bytes_total{link="dcn"}' in snap
+
+
+# -- spans inside Engine.step, on the profiler's clock ----------------------
+
+PROGRAM = ("sched.", "engine.", "kv.")
+PREFILL_CHILDREN = ["kv.grow", "engine.prefill.launch", "kv.write_prefill",
+                    "engine.prefill.fence", "engine.sample_first"]
+DECODE_CHILDREN = ["kv.grow", "engine.decode.stage", "engine.decode.launch",
+                   "engine.decode.fence", "engine.decode.commit"]
+
+
+def _serve(profile_dir=None, telemetry=False):
+    """Serve three prompts over two slots (one queues), under the
+    profiler when ``profile_dir`` is given; returns the engine, the
+    tokens, and the program's host spans (start_ns, end_ns, name, stats)
+    in start order."""
+    cfg, params = _model()
+    eng = Engine(cfg, params, _ecfg(telemetry=telemetry))
+    reqs = [eng.submit(p, GenerateConfig(max_new_tokens=4))
+            for p in _prompts(cfg)]
+    if profile_dir is None:
+        eng.run()
+        return eng, [list(r.generated) for r in reqs], []
+    jax.profiler.start_trace(str(profile_dir))
+    try:
+        eng.run()
+    finally:
+        jax.profiler.stop_trace()
+    path = next(profile_dir.rglob("*.xplane.pb"))
+    profile = jax.profiler.ProfileData.from_file(str(path))
+    spans = [(ev.start_ns, ev.start_ns + ev.duration_ns, ev.name,
+              dict(ev.stats))
+             for plane in profile.planes if plane.name == "/host:CPU"
+             for line in plane.lines for ev in line.events
+             if ev.name.startswith(PROGRAM)]
+    spans.sort(key=lambda s: (s[0], -s[1]))
+    return eng, [list(r.generated) for r in reqs], spans
+
+
+def _children(spans, parent):
+    inside = [s for s in spans if s is not parent
+              and parent[0] <= s[0] and s[1] <= parent[1]]
+    return [c for c in inside
+            if not any(o is not c and o[0] <= c[0] and c[1] <= o[1]
+                       for o in inside)]
+
+
+def test_engine_step_spans_nest_with_their_stats(tmp_path):
+    eng, _, spans = _serve(tmp_path)
+    prompts = _prompts(eng.cfg)
+    prefills = [s for s in spans if s[2] == "engine.prefill"]
+    assert sorted(p[3]["request"] for p in prefills) == [0, 1, 2]
+    for p in prefills:
+        kids = _children(spans, p)
+        assert [k[2] for k in kids] == PREFILL_CHILDREN
+        n = len(prompts[p[3]["request"]])
+        assert p[3]["prompt_len"] == n
+        assert (p[3]["start"], p[3]["end"]) == (0, n)
+        assert kids[1][3]["bucket"] == max(8, 1 << (n - 1).bit_length())
+    decodes = [s for s in spans if s[2] == "engine.decode"]
+    assert len(decodes) == eng.decode_steps
+    for d in decodes:
+        assert [k[2] for k in _children(spans, d)] == DECODE_CHILDREN
+        assert 1 <= d[3]["batch"] <= 2
+    assert max(d[3]["batch"] for d in decodes) == 2
+    admits = [s for s in spans if s[2] == "sched.admit"]
+    assert len(admits) == eng.step_count
+    assert [a[3]["admitted"] for a in admits if a[3]["admitted"]] == [2, 1]
+    assert admits[0][3]["waiting"] == 1
+    # nothing of the program lies outside those three kinds of span
+    tops = [s for s in spans if not any(
+        o is not s and o[0] <= s[0] and s[1] <= o[1] for o in spans)]
+    assert {s[2] for s in tops} == {"sched.admit", "engine.prefill",
+                                    "engine.decode"}
+
+
+def test_profiler_changes_no_token_and_adds_no_fence(tmp_path, monkeypatch):
+    inner = jax.block_until_ready
+    calls = []
+
+    def counted(x):
+        calls.append(1)
+        return inner(x)
+
+    monkeypatch.setattr(jax, "block_until_ready", counted)
+    eng, plain, _ = _serve()
+    n_plain = len(calls)
+    calls.clear()
+    eng2, traced, spans = _serve(tmp_path)
+    assert spans and traced == plain
+    assert eng2.step_count == eng.step_count
+    assert len(calls) == n_plain
+
+
+def test_phase_walls_are_launch_through_fence():
+    eng, _, _ = _serve(telemetry=True)
+    doc = eng.obs.export_trace()
+    assert validate_trace(doc) == []
+    xs = [e for e in doc["traceEvents"] if e["ph"] == "X"]
+    for phase in ("prefill", "decode"):
+        launch = [e for e in xs if e["name"] == f"engine.{phase}.launch"]
+        fence = [e for e in xs if e["name"] == f"engine.{phase}.fence"]
+        assert len(launch) == len(fence) == eng.phases[phase].steps
+        wall_us = sum(f["ts"] + f["dur"] - s["ts"]
+                      for s, f in zip(launch, fence))
+        assert wall_us == pytest.approx(eng.phases[phase].wall_s * 1e6,
+                                        rel=1e-6)
+    # a prefill's spans sit on its slot's track, a decode step's on the
+    # engine's
+    tids = {e["name"]: e["tid"] for e in xs}
+    assert tids["engine.decode.fence"] == ENGINE_TID
+    assert tids["kv.write_prefill"] >= SLOT_TID0
+
+
+def test_span_helper_writes_both_traces(tmp_path):
+    obs = Telemetry()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with span(obs, "engine.x", 3, LIFECYCLE_TID, a=1) as outer:
+            with span(None, "kv.y") as inner:
+                pass
+            outer.set(b=2)
+    finally:
+        jax.profiler.stop_trace()
+    assert outer.t0 <= inner.t0 <= inner.t1 <= outer.t1
+    (ev,) = [e for e in obs.export_trace()["traceEvents"]
+             if e["ph"] == "X"]
+    assert (ev["name"], ev["pid"], ev["tid"]) == ("engine.x", 3,
+                                                  LIFECYCLE_TID)
+    assert ev["args"] == {"a": 1, "b": 2}
+    profile = jax.profiler.ProfileData.from_file(
+        str(next(tmp_path.rglob("*.xplane.pb"))))
+    host = {ev.name: dict(ev.stats)
+            for plane in profile.planes if plane.name == "/host:CPU"
+            for line in plane.lines for ev in line.events}
+    assert host["engine.x"] == {"a": 1, "b": 2} and host["kv.y"] == {}
 
 
 # -- harvest / attainment --------------------------------------------------
