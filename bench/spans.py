@@ -9,8 +9,8 @@ boundary inside ``Engine.step`` (``repro.obs.trace.span``): ``sched.admit``;
 ``engine.decode.launch``, ``engine.decode.fence`` and
 ``engine.decode.commit``.  They lie in the ``/host:CPU`` plane on the
 clock of the device's ``XLA Ops``, nested inside the harness's
-``engine.step``, and carry stats: ``request`` and ``prompt_len`` on
-``engine.prefill``, ``batch`` on ``engine.decode``.
+``engine.step``, and carry stats: ``request`` on ``engine.prefill``,
+``batch`` on ``engine.decode``.
 
 This module keeps them (every host event whose name starts with one of
 ``PREFIXES``, but for the harness's own spans) and does the arithmetic the span metrics share:
@@ -357,15 +357,7 @@ def main() -> int:
     profile = trace_mod.load(Path(args.trace_dir))
     summary = trace_mod.summarize(profile, chips=1)
     spans = program_spans(profile, summary.window)
-    out = report(summary, spans)
-    # decode programs chip 0 ran in the window, for a program without spans
-    lo, hi = summary.window
-    out["decode_programs"] = sum(
-        1 for plane in profile.planes if plane.name == "/device:TPU:0"
-        for line in plane.lines if line.name == trace_mod.MODULES_LINE
-        for ev in line.events
-        if "decode" in ev.name and lo <= float(ev.start_ns) <= hi)
-    print(json.dumps(out), flush=True)
+    print(json.dumps(report(summary, spans)), flush=True)
     if args.cut:
         with gzip.open(args.cut, "wt") as f:
             f.write(cut(profile, summary, spans))

@@ -729,7 +729,7 @@ def run_trace_smoke(args, kwargs) -> dict:
             f"exported trace fails validation ({len(errors)} errors): "
             + "; ".join(errors[:5]))
     names = {ev.get("name") for ev in doc["traceEvents"]}
-    missing = {"prefill_chunk", "decode_step", "migrate_in"} - names
+    missing = {"engine.prefill", "engine.decode", "migrate_in"} - names
     if missing:
         raise RuntimeError(
             f"trace is missing required span names {sorted(missing)}; "

@@ -30,10 +30,10 @@ from . import clock
 from .attainment import AttainmentTracker, AttainmentWindow
 from .metrics import Registry, harvest_serve
 from .trace import (ENGINE_TID, LIFECYCLE_TID, ROUTER_PID, SLOT_TID0,
-                    Span, Tracer, span, validate_trace)
+                    Tracer, span, validate_trace)
 
 __all__ = [
-    "Telemetry", "Tracer", "Span", "span", "validate_trace", "Registry",
+    "Telemetry", "Tracer", "span", "validate_trace", "Registry",
     "harvest_serve",
     "AttainmentTracker", "AttainmentWindow", "clock",
     "ENGINE_TID", "LIFECYCLE_TID", "SLOT_TID0", "ROUTER_PID",

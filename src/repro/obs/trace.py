@@ -160,7 +160,7 @@ class Tracer:
         return doc
 
 
-class Span:
+class _Span:
     """One host span, written to two traces under one name and one set of
     stats: always a ``jax.profiler.TraceAnnotation`` (so it lands in any
     profiler trace on the host clock the device's ``XLA Ops`` share), and
@@ -168,7 +168,8 @@ class Span:
     attached.  ``t0``/``t1`` are the :func:`clock.now` stamps at entry and
     exit, for callers whose phase walls must be the span's own interval.
     ``set`` adds stats known only inside the span; ``tid`` may be set
-    before exit when the Chrome track is known only then."""
+    before exit when the Chrome track is known only then; ``drop`` keeps
+    the Chrome event out when the work the span names did not happen."""
 
     __slots__ = ("obs", "name", "pid", "tid", "stats", "t0", "t1", "_ann")
 
@@ -181,7 +182,10 @@ class Span:
         self.stats.update(stats)
         self._ann.set_metadata(**stats)
 
-    def __enter__(self) -> "Span":
+    def drop(self) -> None:
+        self.obs = None
+
+    def __enter__(self) -> "_Span":
         self._ann = TraceAnnotation(self.name, **self.stats)
         self._ann.__enter__()
         self.t0 = clock.now()
@@ -196,11 +200,11 @@ class Span:
 
 
 def span(obs, name: str, pid: int = 0, tid: int = ENGINE_TID,
-         **stats) -> Span:
+         **stats) -> _Span:
     """``with span(engine.obs, "engine.decode", pid, batch=n):`` — see
-    :class:`Span`.  Costs about a microsecond with no profiler running
+    :class:`_Span`.  Costs about a microsecond with no profiler running
     and ``obs`` None.  Names are ``<layer>.<what>``."""
-    return Span(obs, name, pid, tid, stats)
+    return _Span(obs, name, pid, tid, stats)
 
 
 _REQUIRED = ("ph", "name", "pid", "tid")

@@ -418,18 +418,16 @@ class Engine:
         of the chip."""
         sched, obs, pid = self._sched, self.obs, self._obs_pid
         n_done = len(sched.finished)
-        with span(obs, "sched.admit", pid) as sp:
+        with span(obs, "sched.admit", pid):
             admitted = sched.admit()
             for req in admitted:
                 self._init_sampling_row(req)
                 if req.state is RequestState.RUNNING:
                     self._restore_decode_row(req)        # swap-resume
-            sp.set(admitted=len(admitted), waiting=len(sched.waiting))
         work = sched.prefill_work()
         for req, start, end in work:
             with span(obs, "engine.prefill", pid, SLOT_TID0 + req.slot,
-                      request=req.request_id,
-                      prompt_len=len(req.fill_tokens), start=start, end=end):
+                      request=req.request_id, start=start, end=end):
                 self._run_prefill(req, start, end)
         running = sched.decode_requests()
         if running:
@@ -597,8 +595,7 @@ class Engine:
         bucket = (_bucket_len(fill_len, self.ecfg.prefill_bucket)
                   if bucketed else end - start)
         # the phase wall runs from this launch to the fence below
-        with span(obs, "engine.prefill.launch", pid, tid,
-                  bucket=bucket) as launch:
+        with span(obs, "engine.prefill.launch", pid, tid) as launch:
             if bucketed:
                 toks = np.zeros((1, bucket), np.int32)
                 toks[0, :fill_len] = fill

@@ -704,6 +704,7 @@ class Scheduler:
                       bytes=int(snap.nbytes)) as sp:
                 slot = self.kv.swap_in(snap)
                 if slot is None:
+                    sp.drop()               # no restore happened
                     return False
                 sp.tid = SLOT_TID0 + slot
                 jax.block_until_ready(self.kv.pools)

@@ -311,9 +311,7 @@ def test_engine_step_spans_nest_with_their_stats(tmp_path):
         kids = _children(spans, p)
         assert [k[2] for k in kids] == PREFILL_CHILDREN
         n = len(prompts[p[3]["request"]])
-        assert p[3]["prompt_len"] == n
         assert (p[3]["start"], p[3]["end"]) == (0, n)
-        assert kids[1][3]["bucket"] == max(8, 1 << (n - 1).bit_length())
     decodes = [s for s in spans if s[2] == "engine.decode"]
     assert len(decodes) == eng.decode_steps
     for d in decodes:
@@ -322,8 +320,6 @@ def test_engine_step_spans_nest_with_their_stats(tmp_path):
     assert max(d[3]["batch"] for d in decodes) == 2
     admits = [s for s in spans if s[2] == "sched.admit"]
     assert len(admits) == eng.step_count
-    assert [a[3]["admitted"] for a in admits if a[3]["admitted"]] == [2, 1]
-    assert admits[0][3]["waiting"] == 1
     # nothing of the program lies outside those three kinds of span
     tops = [s for s in spans if not any(
         o is not s and o[0] <= s[0] and s[1] <= o[1] for o in spans)]
@@ -391,6 +387,40 @@ def test_span_helper_writes_both_traces(tmp_path):
             for plane in profile.planes if plane.name == "/host:CPU"
             for line in plane.lines for ev in line.events}
     assert host["engine.x"] == {"a": 1, "b": 2} and host["kv.y"] == {}
+
+
+def test_failed_swap_in_records_no_span():
+    """A swap-in that gets no slot or page records no ``swap_in`` event;
+    the restore that follows records one."""
+    cfg, params = _model()
+    eng = Engine(cfg, params, _ecfg(max_len=16, num_pages=6,
+                                    preempt_mode="swap", telemetry=True))
+    gen = GenerateConfig(max_new_tokens=8)
+    for p in _prompts(cfg, n=2):
+        eng.submit(p, gen)
+    sched, kv = eng._sched, eng._kv
+    while not sched.preempted:
+        eng.step()
+    req = sched.preempted[0]
+    # step on until the pool could take the restore (the other request
+    # finished), then make that restore fail
+    while kv.swap_in_pages_needed(req.swap_snapshot) > \
+            kv.available_page_count:
+        eng.step()
+
+    def swaps():
+        return [e for e in eng.obs.export_trace()["traceEvents"]
+                if e["ph"] == "X" and e["name"] == "swap_in"]
+
+    kv.swap_in = lambda snap, slot=None: None
+    assert not sched._resume(req)
+    assert swaps() == []
+    del kv.swap_in
+    eng.run()
+    doc = eng.obs.export_trace()
+    assert validate_trace(doc) == []
+    assert len(swaps()) == sum(1 for e in doc["traceEvents"]
+                               if e["name"] == "swap_out") > 0
 
 
 # -- harvest / attainment --------------------------------------------------
