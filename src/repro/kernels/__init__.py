@@ -25,10 +25,11 @@ VMEM budgets (fp32 accounting; ~16 MiB/core usable)
 * flash_attention: resident K/V stream of one KV head + 3 blocks
   ~ 2*Sk*hd*4 B — Sk <= 8192, hd = 128 fits comfortably; longer sequences
   use the host-level q-chunk wrapper.
-* paged_attention (decode): one (page_size, hd) K slab + V slab + the
-  (G, hd) query/accumulator and (G, 1) softmax carries — well under 1 MiB
-  per grid step, leaving the pipeline free to prefetch pages ahead
-  through the scalar-prefetched block table.
+* paged_attention (decode): the live-page walk double-buffers a
+  (KV, C * page, hd) K slab and V slab, C * page ~ 256 keys, held under
+  4 MiB (2 MiB for qwen3-0.6b); the per-block walk of quantized pools
+  holds one (page_size, hd) K slab + V slab + the (G, hd)
+  query/accumulator and (G, 1) softmax carries per grid step.
 * mla_paged_attention: (page, r + rope_hd) slabs + (H, r) accumulator;
   r <= 576 keeps this under ~2 MiB even at 128 heads.
 """

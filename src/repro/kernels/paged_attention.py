@@ -16,19 +16,26 @@ decode step (the ``paged_attention`` named scope marks the region;
 core/roofline/hlo_cost.TRACKED_SCOPES prices it, substitute.py swaps the
 reference's gather traffic for the kernel's).
 
-Kernel layout (GQA):
+Kernel layout (GQA decode, the live-page walk):
+  grid (num_slots,); the block table and per-slot positions ride in as
+  *scalar prefetch*, and each program loops over only its slot's live
+  pages, ``pos // page + 1`` of them, C pages per loop step.  The pools
+  are head-major, (P, KV, page, hd), so one page of every KV head is one
+  contiguous (KV, page, hd) block; explicit async copies land C of them
+  in a (KV, C * page, hd) VMEM slab per stream, double-buffered across
+  loop steps, and each head runs one online-softmax update over its
+  (G, C * page) scores per step.  Dead table entries get no copy.
+
+Per-block grid walk (quantized pools; the bitwise partner of
+``pipeline="double"``; the verify kernels):
   grid (num_slots, kv_heads, n_blocks); per grid step one (page, hd) K
-  slab and V slab of the mapped KV head are resident in VMEM.  The pools
-  are head-major, (P, KV, page, hd), so that slab is the trailing two
-  dims of the pool — the only block shape Mosaic accepts for a single
-  head (a (page, 1, hd) block of a (P, page, KV, hd) pool is refused for
-  its unaligned second-minor dim).  The block
-  table and per-slot positions ride in as *scalar prefetch* so the page
-  -> HBM address mapping is known before the body runs — Pallas
-  double-buffers the page fetches across the innermost grid dim, i.e. the
-  kernel "walks the block table" with the DMA engine.  Online softmax
-  carries (m, l, acc) in VMEM scratch across the block walk; the output
-  block is written on the last block.
+  slab and V slab of the mapped KV head are resident in VMEM — the
+  trailing two dims of the head-major pool, the only single-head block
+  Mosaic accepts.  Pallas double-buffers the page fetches across the
+  innermost grid dim; online softmax carries (m, l, acc) in VMEM scratch
+  across the walk of every table entry, dead ones included; the output
+  block is written on the last block.  A grid step costs a fixed ~0.35
+  us on v5e, which is why decode no longer takes this path.
 
 MLA variant: attention runs entirely in the compressed latent space
 (absorbed form, DeepSeek-V2 §5): scores = q_lat @ c_kv^T + q_rope @
@@ -36,14 +43,15 @@ k_rope^T over (page, kv_lora + rope_hd) slabs, acc accumulates w @ c_kv.
 The cache line is ~57x smaller than the equivalent GQA line, so decode
 intensity I = W/Q rises by the same factor — the paper's eq. 1 lever.
 
-VMEM budget (per grid step, fp32 accounting): GQA holds 2 * page_size *
-hd K/V slabs + (G, hd) q/acc + 2 * (G, 1) carries; MLA holds page_size *
-(r + rope_hd) slabs + (H, r + rope_hd) queries + (H, r) acc.  With
-page_size 16-128, hd/r <= 576 this is well under 1 MiB — far below the
-~16 MiB/core limit, leaving the pipeline free to prefetch ahead.
+VMEM budget: the live-page walk's four (KV, C * page, hd) slabs are held
+under 4 MiB (2 MiB for qwen3-0.6b); per-block grid steps hold 2 *
+page_size * hd K/V slabs + (G, hd) q/acc + 2 * (G, 1) carries (GQA) or
+page_size * (r + rope_hd) slabs + (H, r + rope_hd) queries + (H, r) acc
+(MLA), well under 1 MiB — all far below the ~16 MiB/core limit.
 
 Ragged contexts: slots own different numbers of live pages; dead block
--table entries point at the reserved trash page (physical page 0) and the
+-table entries point at the reserved trash page (physical page 0), which
+the live-page walk never copies and the per-block walks mask: the
 ``k_pos <= pos`` mask zeroes their probability exactly.  Idle lanes
 (pos = 0, all-trash tables) compute a harmless garbage row the engine
 discards — same contract as the jnp reference.
@@ -66,8 +74,10 @@ two VMEM slabs per stream, DMA semaphores, and a one-block lookahead:
 start the copy of page b+1 into slab ``1 - (b % 2)`` before waiting on
 page b, so the HBM->VMEM transfer of the next page hides behind the
 current page's flash-attention math.  The compute per block is the exact
-op sequence of the single-buffered kernel (same f32 online-softmax chain,
-same order), so ``pipeline="double"`` is bit-identical to ``"off"``; the
+op sequence of the single-buffered per-block kernel (same f32 online-
+softmax chain, same order), so ``pipeline="double"`` is bit-identical to
+the per-block grid walk (``paged_attention_blocks`` for GQA decode, and
+``"off"`` for the other kernels); the
 q/o slabs are fetched ONCE per program instead of re-read per grid step,
 which the VMEM pricing below reflects (``pipeline`` kwarg).  Selection
 rides the kernel registry (kernels/ops.py ``pipeline=off|double``),
@@ -82,6 +92,7 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -272,9 +283,9 @@ def _row_to_column(row: jax.Array) -> jax.Array:
 def _paged_decode_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
                          page_size: int, scale: float, soft_cap: float,
                          quantized: bool = False):
-    """One (slot, kv_head, block) grid step of the GQA decode walk.  When
-    ``quantized`` two float32 (1, page) scale rows of the mapped head
-    follow k/v and dequantize the streamed slab in VMEM."""
+    """One (slot, kv_head, block) grid step of the per-block GQA decode
+    walk.  When ``quantized`` two float32 (1, page) scale rows of the
+    mapped head follow k/v and dequantize the streamed slab in VMEM."""
     if quantized:
         ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
@@ -337,14 +348,40 @@ def paged_attention(
     v_scale: Optional[jax.Array] = None,
     interpret: bool = False, pipeline: str = "off",
 ) -> jax.Array:
-    """Pallas GQA paged decode; same contract as the reference."""
+    """Pallas GQA paged decode; same contract as the reference.
+
+    ``pipeline="off"`` runs the live-page walk on unquantized pools and
+    the per-block grid walk (:func:`paged_attention_blocks`) on quantized
+    ones; ``"double"`` runs the double-buffered per-block walk."""
     _check_pipeline(pipeline)
-    B, KV, G, hd = q.shape
+    G = q.shape[2]
     if pipeline == "double":
         return _gqa_paged_double(
             q, k_pool, v_pool, block_tables, pos, n_group=G, scale=scale,
             soft_cap=soft_cap, k_scale=k_scale, v_scale=v_scale,
             interpret=interpret)
+    if walks_live_pages(quantized=k_scale is not None, pipeline=pipeline):
+        return _gqa_live_walk(q, k_pool, v_pool, block_tables, pos,
+                              scale=scale, soft_cap=soft_cap,
+                              interpret=interpret)
+    return paged_attention_blocks(
+        q, k_pool, v_pool, block_tables, pos, scale=scale,
+        soft_cap=soft_cap, k_scale=k_scale, v_scale=v_scale,
+        interpret=interpret)
+
+
+def paged_attention_blocks(
+    q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
+    block_tables: jax.Array, pos: jax.Array, *,
+    scale: float, soft_cap: float = 0.0,
+    k_scale: Optional[jax.Array] = None,
+    v_scale: Optional[jax.Array] = None,
+    interpret: bool = False,
+) -> jax.Array:
+    """The per-block grid walk, grid (B, KV, n_blocks): one head's page
+    per grid step over every table entry, dead ones included.  It serves
+    quantized pools and is the bitwise partner of ``pipeline="double"``."""
+    B, KV, G, hd = q.shape
     page_size = k_pool.shape[2]
     n_blocks = block_tables.shape[1]
     quantized = k_scale is not None
@@ -374,8 +411,171 @@ def paged_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
         interpret=interpret,
-        name="paged_attention_decode",
+        name="paged_attention_blocks",
     )(*args)
+
+
+# Live-page walk: the GQA decode kernel for unquantized pools.  A step of
+# the per-block grid costs a fixed ~0.35 us on v5e whatever its work, and
+# that grid walks B x KV x n_blocks steps, dead table entries included.
+# This walk runs one program per slot and loops over that slot's live
+# pages only, C pages of all KV heads per loop step.
+
+LIVE_WALK_KEYS = 256                 # keys (C * page) one loop step aims for
+LIVE_WALK_VMEM_BYTES = 4 << 20       # cap on the K+V slabs, both buffers
+
+
+def walks_live_pages(*, quantized: bool, pipeline: str) -> bool:
+    """Whether :func:`paged_attention` runs the live-page walk: decode at
+    ``pipeline="off"`` over unquantized pools."""
+    return pipeline == "off" and not quantized
+
+
+def live_walk_chunk_pages(page_size: int, kv_heads: int, head_dim: int,
+                          itemsize: int) -> int:
+    """Pages C the live-page walk copies per loop step: C * page ~ 256
+    keys, halved until the double-buffered K and V slabs, 4 x (KV,
+    C * page, hd), fit :data:`LIVE_WALK_VMEM_BYTES`.  qwen3-0.6b (KV 8,
+    hd 128, bf16, page 16): C = 16, 2 MiB of slabs."""
+    c = max(1, LIVE_WALK_KEYS // page_size)
+    while c > 1 and (4 * kv_heads * c * page_size * head_dim * itemsize
+                     > LIVE_WALK_VMEM_BYTES):
+        c //= 2
+    return c
+
+
+def live_walk_pages(pos: np.ndarray, page_size: int, n_blocks: int) -> int:
+    """Table entries one live-page walk copies over host positions
+    ``pos`` (B,): each slot's ``pos // page + 1`` live pages, one for an
+    idle lane (pos 0), as in :func:`_live_walk_kernel`."""
+    return int(np.minimum(pos // page_size + 1, n_blocks).sum())
+
+
+def _live_walk_kernel(bt_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
+                      k_buf, v_buf, sems, *, page_size: int, chunk: int,
+                      n_blocks: int, scale: float, soft_cap: float):
+    """Grid (B,): slot b's live pages, ``chunk`` pages per loop step.
+
+    Page j of all KV heads is one contiguous (KV, page, hd) block of the
+    head-major pool; it lands at rows ``(j % chunk) * page`` of the
+    (KV, chunk * page, hd) slab, so each head reads one (chunk * page, hd)
+    slab.  The next chunk's copies start before the current chunk's
+    wait (two slabs per stream).  Dead entries get no copy; their V rows
+    are zeroed instead, since ``p * v`` is NaN for p = 0 and v = NaN,
+    and their keys fall past ``pos`` under the mask."""
+    b = pl.program_id(0)
+    pos = pos_ref[b]
+    n_live = jnp.minimum(pos // page_size + 1, n_blocks)     # live pages
+    n_chunks = (n_live + chunk - 1) // chunk
+    kv_heads, rows, hd = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
+    keys = chunk * page_size
+
+    def page_copies(slot, i, j):
+        dst = (slot, slice(None), pl.ds(i * page_size, page_size))
+        page = bt_ref[b, j]
+        return (pltpu.make_async_copy(k_hbm.at[page], k_buf.at[dst],
+                                      sems.at[slot]),
+                pltpu.make_async_copy(v_hbm.at[page], v_buf.at[dst],
+                                      sems.at[slot]))
+
+    def fetch(c, slot):
+        for i in range(chunk):
+            j = c * chunk + i
+
+            @pl.when(j < n_live)
+            def _start():
+                for dma in page_copies(slot, i, j):
+                    dma.start()
+
+            @pl.when(j >= n_live)
+            def _zero():
+                v_buf[slot, :, pl.ds(i * page_size, page_size)] = (
+                    jnp.zeros((kv_heads, page_size, hd), v_buf.dtype))
+
+    def wait(c, slot):
+        for i in range(chunk):
+            j = c * chunk + i
+
+            @pl.when(j < n_live)
+            def _wait():
+                for dma in page_copies(slot, i, j):
+                    dma.wait()
+
+    fetch(0, 0)
+    qs = [q_ref[0, h] for h in range(kv_heads)]             # (rows, hd)
+
+    def body(c, carry):
+        slot = jax.lax.rem(c, 2)
+
+        @pl.when(c + 1 < n_chunks)
+        def _prefetch():
+            fetch(c + 1, 1 - slot)
+
+        wait(c, slot)
+        k_pos = c * keys + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, keys), 1)
+        out = []
+        for h, (m_prev, l_prev, acc_prev) in enumerate(carry):
+            k = k_buf[slot, h]                              # (keys, hd)
+            v = v_buf[slot, h]
+            s = jnp.einsum("gd,td->gt", qs[h], k,
+                           preferred_element_type=jnp.float32) * scale
+            if soft_cap > 0:
+                s = jnp.tanh(s / soft_cap) * soft_cap
+            s = jnp.where(k_pos <= pos, s, NEG_INF)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc_new = acc_prev * alpha + jnp.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+            out.append((m_new, l_new, acc_new))
+        return tuple(out)
+
+    init = tuple((jnp.full((rows, 1), NEG_INF, jnp.float32),
+                  jnp.zeros((rows, 1), jnp.float32),
+                  jnp.zeros((rows, hd), jnp.float32))
+                 for _ in range(kv_heads))
+    carry = jax.lax.fori_loop(0, n_chunks, body, init)
+    for h, (_, l, acc) in enumerate(carry):
+        o_ref[0, h] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+
+def _gqa_live_walk(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
+                   block_tables: jax.Array, pos: jax.Array, *,
+                   scale: float, soft_cap: float,
+                   interpret: bool) -> jax.Array:
+    """q (B, KV, G, hd) -> (B, KV, G, hd) over unquantized head-major
+    pools (P, KV, page, hd)."""
+    B, KV, G, hd = q.shape
+    page_size = k_pool.shape[2]
+    n_blocks = block_tables.shape[1]
+    chunk = min(n_blocks, live_walk_chunk_pages(
+        page_size, KV, hd, k_pool.dtype.itemsize))
+    kernel = functools.partial(
+        _live_walk_kernel, page_size=page_size, chunk=chunk,
+        n_blocks=n_blocks, scale=scale, soft_cap=soft_cap)
+    qo_spec = pl.BlockSpec((1, KV, G, hd), lambda b, bt, ps: (b, 0, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,                  # block tables + positions
+        grid=(B,),
+        in_specs=[qo_spec,
+                  pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+                  pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)],
+        out_specs=qo_spec,
+        scratch_shapes=[
+            pltpu.VMEM((2, KV, chunk * page_size, hd), k_pool.dtype),
+            pltpu.VMEM((2, KV, chunk * page_size, hd), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
+        interpret=interpret,
+        name="paged_attention_decode",
+    )(block_tables, pos.astype(jnp.int32), q, k_pool, v_pool)
 
 
 def _mla_paged_decode_kernel(bt_ref, pos_ref, ql_ref, qr_ref, c_ref, r_ref,
@@ -971,19 +1171,21 @@ def _mla_paged_double(qlf: jax.Array, qrf: jax.Array, c_pool: jax.Array,
 # The HBM ledger prices the page walk once per line (kv_line_bytes * L).
 # The VMEM level sees MORE traffic than that: the streamed slabs cross it
 # on their way in, the query slab is re-read from VMEM on every block step
-# of the grid, and the fp32 softmax carries (m, l, acc) in scratch are read
-# AND written once per block step.  These formulas are derived from the
-# BlockSpecs and scratch shapes of the kernels above — if a kernel's grid
-# or scratch changes, the pricing here must change with it (the bench
-# --hierarchy crosscheck is the tripwire).
+# of a per-block grid, and the fp32 softmax carries (m, l, acc) are read
+# AND written once per block step (once per chunk in the live-page walk).
+# These formulas are derived from the BlockSpecs and scratch shapes of the
+# kernels above — if a kernel's grid or scratch changes, the pricing here
+# must change with it (serve/crosscheck.py walks the grids independently
+# and is the tripwire).
 # --------------------------------------------------------------------------
 
 
 def live_blocks(context_len: int, page_size: int, n_q: int = 1) -> int:
     """Pages holding live KV for a slot whose LAST query sits at position
-    ``context_len + n_q - 2`` (decode: n_q=1 -> lines 0..L-1).  The kernel
-    grid walks the whole block table, but steps beyond the live prefix
-    mask to no-ops; we price only the live walk, like the HBM ledger."""
+    ``context_len + n_q - 2`` (decode: n_q=1 -> lines 0..L-1).  The
+    live-page walk copies exactly these; a per-block grid walks the whole
+    block table, but steps beyond the live prefix mask to no-ops, so we
+    price only the live walk, like the HBM ledger."""
     lines = max(1, int(context_len) + int(n_q) - 1)
     return -(-lines // int(page_size))
 
@@ -996,11 +1198,17 @@ def paged_decode_vmem_bytes(
     """VMEM bytes one slot moves in the GQA paged decode (``n_q == 1``)
     or verify (``n_q == T``) kernel.
 
-    Grid is (B, KV, n_blocks); per (kv_head, block) step the kernel
-    streams one (page, hd) K slab and one V slab HBM->VMEM, re-reads the
-    (G * n_q, hd) query slab, and reads+writes the fp32 carries
-    (m, l: (rows, 1) each; acc: (rows, hd)).  The output flush and the
-    n_q freshly appended cache lines cross VMEM once.
+    Per-block grid (B, KV, n_blocks): per (kv_head, block) step the
+    kernel streams one (page, hd) K slab and one V slab HBM->VMEM,
+    re-reads the (G * n_q, hd) query slab, and reads+writes the fp32
+    carries (m, l: (rows, 1) each; acc: (rows, hd)).  The output flush
+    and the n_q freshly appended cache lines cross VMEM once.
+
+    Decode over unquantized pools at ``pipeline="off"`` runs the
+    live-page walk (:func:`walks_live_pages`): the same live pages
+    stream in, the query slab is fetched once per (slot) program, and the
+    carries are updated once per chunk of :func:`live_walk_chunk_pages`
+    pages instead of once per block.
 
     ``pipeline="double"`` prices the two-slab manual-DMA kernel: the
     block walk runs inside one (slot, kv_head) program, so the query
@@ -1017,10 +1225,16 @@ def paged_decode_vmem_bytes(
     rows = g * n_q
     nb = live_blocks(context_len, page_size, n_q)
     q_steps = nb if pipeline == "off" else 1
+    carry_steps = nb
+    if n_q == 1 and walks_live_pages(quantized=scale_isize > 0,
+                                     pipeline=pipeline):
+        chunk = live_walk_chunk_pages(page_size, kv_heads, head_dim,
+                                      kv_isize or isize)
+        q_steps, carry_steps = 1, -(-nb // chunk)
     kv_line = head_dim * (kv_isize or isize) + scale_isize
     stream = kv_heads * nb * 2 * page_size * kv_line
     q_reread = kv_heads * q_steps * rows * head_dim * isize
-    carries = kv_heads * nb * 2 * rows * (head_dim + 2) * 4
+    carries = kv_heads * carry_steps * 2 * rows * (head_dim + 2) * 4
     out = kv_heads * rows * head_dim * isize
     appended = n_q * 2 * kv_heads * kv_line
     return float(stream + q_reread + carries + out + appended)

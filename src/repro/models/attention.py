@@ -182,9 +182,11 @@ def paged_pool_defs(cfg: ModelConfig, num_pages: int, page_size: int
     Pages carry no batch dim — a per-slot block table maps logical block
     index -> physical page, so slots of different lengths share one pool
     (vLLM-style paging; the block table is shared across layers).  Heads
-    sit ahead of the page lines so one head's (page, hd) slab is the
-    trailing two dims of the pool: the block the Pallas page walk reads
-    per grid step (kernels/paged_attention.py).
+    sit ahead of the page lines so one page of every head is one
+    contiguous (KV, page, hd) block, the copy the live-page walk makes,
+    and one head's (page, hd) slab is the trailing two dims of the pool,
+    the block the per-block walk reads per grid step
+    (kernels/paged_attention.py).
 
     With ``cfg.kv_dtype`` quantized (int8 / fp8_e4m3) the k/v pools store
     quantized values plus float32 absmax scales per (page, kv_head, line),

@@ -345,12 +345,16 @@ def _kernel_grid_vmem_walk(cfg, context_len: int, page_size: int,
     pools.  ``pipeline="double"`` walks the two-slab manual-DMA grids
     instead: the block loop lives inside one (slot[, kv_head]) program,
     so the query slab enters VMEM once per program rather than once per
-    block step (streamed pages / carries / out are the same walk).  The
-    closed-form pricing (kernels.paged_decode_vmem_bytes) must agree
-    with this walk; drift means someone changed the kernel's block
-    geometry without repricing the ledger."""
+    block step (streamed pages / carries / out are the same walk).  GQA
+    decode over unquantized pools at ``pipeline="off"`` walks the
+    live-page kernel: one program per slot fetches the query once and
+    loops over chunks of C pages of every head, one carry update per
+    chunk.  The closed-form pricing (kernels.paged_decode_vmem_bytes)
+    must agree with this walk; drift means someone changed the kernel's
+    block geometry without repricing the ledger."""
     from repro.kernels import quantize as kvq
-    from repro.kernels.paged_attention import _check_pipeline, live_blocks
+    from repro.kernels.paged_attention import (_check_pipeline, live_blocks,
+                                               live_walk_chunk_pages)
     _check_pipeline(pipeline)
     isize = jnp.dtype(cfg.dtype).itemsize
     kv_isize = kvq.store_itemsize(cfg.kv_dtype, cfg.dtype)
@@ -367,11 +371,19 @@ def _kernel_grid_vmem_walk(cfg, context_len: int, page_size: int,
                 # quantized pools stream (page, hd) k/v slabs at the
                 # storage itemsize plus a (page,) f32 scale slab each
                 kv_line = hd * kv_isize + s
-                per_step = (2 * page_size * kv_line       # k + v (+scale)
-                            + 2 * rows * (hd + 2) * 4)    # m/l/acc r+w
-                walk = KV * (q_steps * rows * hd * isize  # q block(s)
-                             + nb * per_step
-                             + rows * hd * isize)         # out flush
+                stream = nb * 2 * page_size * kv_line     # k + v (+scale)
+                carry = 2 * rows * (hd + 2) * 4           # m/l/acc r+w
+                if n_q == 1 and pipeline == "off" and not s:
+                    # grid (B,): one query fetch, one carry update a chunk
+                    chunk = live_walk_chunk_pages(page_size, KV, hd,
+                                                  kv_isize)
+                    chunks = -(-nb // chunk)
+                    walk = KV * (rows * hd * isize + stream
+                                 + chunks * carry + rows * hd * isize)
+                else:
+                    walk = KV * (q_steps * rows * hd * isize  # q block(s)
+                                 + stream + nb * carry
+                                 + rows * hd * isize)         # out flush
                 walk += n_q * 2 * KV * kv_line            # appended line
             elif b.mixer == "mla":
                 H, r, dr = (cfg.n_heads, cfg.kv_lora_rank,

@@ -38,6 +38,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.roofline.hardware import ChipSpec, TPU_V5E
+from repro.kernels import ops as kernel_ops
+from repro.kernels import paged_attention as paged_kernels
 from repro.kernels import quantize
 from repro.models import (decode_step, decode_step_paged, init_cache,
                           prefill, prefill_chunk_paged, prefill_padded)
@@ -201,6 +203,7 @@ class Engine:
         self._kv: Optional[PagedKVCache] = None
         self._sched: Optional[Scheduler] = None
         self._decode_fn = None
+        self._live_walk = False
         self._prefill_fn = None
         self._next_token: Optional[np.ndarray] = None
         self._pos: Optional[np.ndarray] = None
@@ -324,6 +327,16 @@ class Engine:
         that keeps the 1x1 mesh byte-identical to this engine."""
         ps, be = self.ecfg.page_size, self.ecfg.kernel_backend
         pl = self.ecfg.pipeline
+        impl = kernel_ops.resolve("paged_attention", be,
+                                  sharded=cfg.tp_axis is not None,
+                                  pipeline=pl)
+        # whether the step's GQA layers run the live-page walk (telemetry)
+        self._live_walk = (
+            any(b.mixer == "attn" for b in cfg.block_pattern)
+            and getattr(impl, "func", None) is paged_kernels.paged_attention
+            and paged_kernels.walks_live_pages(
+                quantized=quantize.is_quantized(cfg.kv_dtype),
+                pipeline=impl.keywords["pipeline"]))
 
         def _decode_sample(p, pools, bt, tok, pos, act, kd, steps, temps,
                            top_ks, top_ps):
@@ -432,7 +445,7 @@ class Engine:
         running = sched.decode_requests()
         if running:
             with span(obs, "engine.decode", pid) as sp:
-                sp.set(batch=self._run_decode(running))
+                sp.set(**self._run_decode(running))
         elif (not admitted and not work
                 and (sched.waiting or sched.preempted)):
             head = (sched.preempted + list(sched.waiting))[0]
@@ -711,12 +724,16 @@ class Engine:
             return running, self._kv.block_tables_for(
                 [r.slot for r in running])
 
-    def _run_decode(self, running: List[Request]) -> int:
-        """One packed decode step; returns how many requests it stepped."""
+    def _run_decode(self, running: List[Request]) -> Dict[str, int]:
+        """One packed decode step; returns the ``engine.decode`` span's
+        stats: ``batch``, the requests it stepped, and with telemetry on
+        and the live-page walk in the step, ``pages``, the table entries
+        one layer's walk copies."""
         kv, obs, pid = self._kv, self.obs, self._obs_pid
         running, bt = self._grow_decode(running, 1)
         if not running:
-            return 0
+            return {"batch": 0}
+        stats = {"batch": len(running)}
         # decode + batched sampling run as ONE jitted step: the host sees
         # only the chosen token ids, never the (B, V) logits.  Argument
         # conversion happens BEFORE the fenced window so the phase wall
@@ -731,6 +748,13 @@ class Engine:
                          jnp.asarray(active), jnp.asarray(self._key_data),
                          jnp.asarray(self._steps), jnp.asarray(self._temps),
                          jnp.asarray(self._top_ks), jnp.asarray(self._top_ps))
+            if obs is not None and self._live_walk:
+                stats["pages"] = paged_kernels.live_walk_pages(
+                    pos, self.ecfg.page_size, bt.shape[1])
+                obs.registry.counter(
+                    "serve_live_page_walks_total",
+                    "decode steps whose GQA layers ran the live-page walk"
+                ).inc()
         with span(obs, "engine.decode.launch", pid) as launch:
             next_tok, kv.pools = self._decode_fn(*step_args)
         # fence BEFORE stamping: dispatch is async, so an unfenced stamp
@@ -760,7 +784,7 @@ class Engine:
                        ici=ici_share, steps=0, tokens=1)
                 self._commit_token(req, int(tok_np[req.slot]), t=t1)
             ph.add(wall_s=t1 - t0, steps=1, tokens=0)
-        return n_active
+        return stats
 
     def _commit_token(self, req: Request, tok: int, first: bool = False,
                       t: Optional[float] = None) -> None:

@@ -260,7 +260,7 @@ class SpecEngine(Engine):
 
     # -- decode = propose -> verify -> accept -> commit --------------------
 
-    def _run_decode(self, running: List[Request]) -> int:
+    def _run_decode(self, running: List[Request]) -> Dict[str, int]:
         kv, s, pid = self._kv, self.scfg, self._obs_pid
         k, T = s.k, s.k + 1
         # the verify step writes T KV lines from context_len - 1 on:
@@ -269,7 +269,7 @@ class SpecEngine(Engine):
         # is clipped onto the trash-margin entries as before
         running, bt = self._grow_decode(running, T)
         if not running:
-            return 0
+            return {"batch": 0}
         active = np.zeros((self.ecfg.num_slots,), bool)
         active[[r.slot for r in running]] = True
         k_eff = None
@@ -353,7 +353,7 @@ class SpecEngine(Engine):
                     req.ledger.add_draft_cost(s.draft_cfg, L, n_fed,
                                               n_decodes, n_active)
             vph.add(wall_s=t1 - t0, steps=1, tokens=0)
-        return len(running)
+        return {"batch": len(running)}
 
     def _preempt(self, req: Request) -> None:
         # the draft proposer's mirrored slot must go with the target's —

@@ -36,6 +36,7 @@ from repro.core.roofline.report import (COMM_HEADER, TIME_BUDGET_HEADER,
                                         TIME_BUDGET_OVERLAP_HEADER,
                                         comm_terms_row, hierarchy_rows,
                                         time_budget_rows)
+from repro.kernels.paged_attention import paged_decode_vmem_bytes
 from repro.models import init_params
 from repro.serve.crosscheck import crosscheck_host, crosscheck_vmem
 from repro.serve.engine import Engine, EngineConfig, GenerateConfig
@@ -65,8 +66,10 @@ CHIP = ChipSpec(
 
 GOLDEN = {
     # arch: (hbm, vmem, attn_vmem, verify_vmem_T4, swap_3_blocks)
-    # at context L=24, active batch B=2, page size 8
-    "qwen3-0.6b": (193024.0, 198528.0, 18304.0, 227328.0, 12288.0),
+    # at context L=24, active batch B=2, page size 8.  qwen3's decode is
+    # the live-page walk: one query fetch and one carry update per chunk
+    # (three pages, one chunk) where the per-block grid paid three
+    "qwen3-0.6b": (193024.0, 195200.0, 14976.0, 227328.0, 12288.0),
     "deepseek-v2-236b": (260416.0, 271808.0, 19392.0, 323328.0, 7680.0),
 }
 
@@ -84,6 +87,24 @@ def test_golden_per_level_bytes(arch):
     # the VMEM level sees every HBM byte pass through plus the kernel's
     # resident re-touches, so it can never undercut the HBM level
     assert vmem > hbm - attn_vmem
+
+
+def test_decode_pricing_follows_the_walk_the_pools_take():
+    """Unquantized decode prices the live-page walk (query once, one
+    carry update per chunk); quantized pools keep the per-block grid's
+    formula (query and carries once per block)."""
+    KV, rows, hd, ps, nb = 2, 2, 16, 8, 5          # context 40: 5 pages
+    kw = dict(context_len=40, page_size=ps, n_heads=4, kv_heads=KV,
+              head_dim=hd, isize=4)
+    carry = 2 * rows * (hd + 2) * 4
+    qo = KV * rows * hd * 4
+    line = hd * 4
+    assert paged_decode_vmem_bytes(**kw) == (
+        KV * nb * 2 * ps * line + qo + KV * 1 * carry + qo + 2 * KV * line)
+    line = hd * 1 + 4                               # int8 + f32 scale
+    assert paged_decode_vmem_bytes(**kw, kv_isize=1, scale_isize=4) == (
+        KV * nb * 2 * ps * line + nb * qo + KV * nb * carry + qo
+        + 2 * KV * line)
 
 
 def test_vmem_bytes_grow_with_context_and_queries():
@@ -196,15 +217,22 @@ def test_time_budget_rows_overlap_columns():
 
 def test_pipeline_pricing_shrinks_vmem_only():
     """pipeline="double" collapses the per-block query re-read to one
-    fetch — the VMEM pricing drops, everything else (HBM, swap) is
-    untouched, and the default stays exactly the GOLDEN values."""
+    fetch — the VMEM pricing of the per-block walks (MLA, verify) drops,
+    everything else (HBM, swap) is untouched, and the default stays
+    exactly the GOLDEN values.  GQA decode at "off" is the live-page
+    walk, which also updates its carries once per chunk instead of once
+    per block: it prices under the double walk."""
     for arch in sorted(GOLDEN):
         cfg = smoke(get_config(arch))
         L, B, ps, T = 24, 2, 8, 4
-        assert attn_kernel_vmem_bytes(cfg, L, ps, pipeline="double") < \
-            attn_kernel_vmem_bytes(cfg, L, ps)
-        assert decode_token_vmem_bytes(cfg, L, B, ps, pipeline="double") < \
-            decode_token_vmem_bytes(cfg, L, B, ps)
+        dbl = attn_kernel_vmem_bytes(cfg, L, ps, pipeline="double")
+        off = attn_kernel_vmem_bytes(cfg, L, ps)
+        dbl_tok = decode_token_vmem_bytes(cfg, L, B, ps, pipeline="double")
+        off_tok = decode_token_vmem_bytes(cfg, L, B, ps)
+        if arch == "qwen3-0.6b":
+            assert off < dbl and off_tok < dbl_tok
+        else:
+            assert dbl < off and dbl_tok < off_tok
         assert verify_step_vmem_bytes(cfg, L, T, B, ps,
                                       pipeline="double") < \
             verify_step_vmem_bytes(cfg, L, T, B, ps)

@@ -22,6 +22,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config, smoke
+from repro.kernels import paged_attention as pa
 from repro.models import init_params
 from repro.obs import Telemetry, clock
 from repro.obs.metrics import Counter, Registry, harvest_serve
@@ -439,6 +440,43 @@ def test_harvest_exposes_ledger_pool_and_latency():
     # harvesting again must not double-count anything
     eng.obs.harvest(eng)
     assert eng.obs.snapshot() == text
+
+
+@pytest.mark.parametrize("backend", ["pallas", "jnp"])
+def test_decode_span_counts_live_walk_pages(backend):
+    """With telemetry on and the live-page walk in the decode step, each
+    ``engine.decode`` event carries ``pages``, the table entries one
+    layer's walk copies for the step's positions, and
+    ``serve_live_page_walks_total`` counts the steps.  The jnp reference
+    walks no pages: neither appears."""
+    cfg, params = _model()
+    eng = Engine(cfg, params, _ecfg(telemetry=True, kernel_backend=backend))
+    reqs = [eng.submit(p, GenerateConfig(max_new_tokens=4))
+            for p in _prompts(cfg)]
+    seen = []
+    step = eng._decode_fn
+
+    def probe(*args):
+        bt, pos = args[2], np.asarray(args[4])
+        seen.append(pa.live_walk_pages(pos, eng.ecfg.page_size,
+                                       bt.shape[1]))
+        return step(*args)
+
+    eng._decode_fn = probe
+    eng.run()
+    assert all(len(r.generated) == 4 for r in reqs)
+    decodes = [e["args"] for e in eng.obs.tracer.events
+               if e["name"] == "engine.decode"]
+    assert len(decodes) == eng.decode_steps == len(seen)
+    text = eng.obs.snapshot()
+    if backend == "pallas":
+        assert [d["pages"] for d in decodes] == seen
+        assert min(seen) >= 2          # two slots: a live page each
+        assert (f"serve_live_page_walks_total {eng.decode_steps}"
+                in text)
+    else:
+        assert all("pages" not in d for d in decodes)
+        assert "serve_live_page_walks_total" not in text
 
 
 def test_attainment_windows_name_the_binding_roof():

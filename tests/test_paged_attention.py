@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.configs import get_config, smoke
 from repro.kernels import ops
@@ -84,6 +85,103 @@ def test_gqa_kernel_idle_trash_lane_is_finite():
     assert np.isfinite(np.asarray(out)).all()
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-6)
+
+
+# -- live-page walk (pipeline="off", unquantized pools) --------------------
+# One program per slot walks only that slot's live pages, C pages of every
+# KV head per loop step.  Each case packs the edges of that walk into the
+# lanes of one batch: an idle all-trash lane, a single live token, a
+# context ending exactly on a chunk edge and one just past it, a full
+# table, and a ragged one.
+
+def _edge_tables(rng, nb, page, chunk, num_pages):
+    ends = [None, 0, chunk * page - 1, min(chunk * page, nb * page - 1),
+            nb * page - 1, rng.randint(0, nb * page)]
+    bt = np.zeros((len(ends), nb), np.int32)
+    pos = np.zeros((len(ends),), np.int32)
+    free = list(rng.permutation(np.arange(1, num_pages)))
+    for b, end in enumerate(ends):
+        if end is None:                       # idle lane: pos 0, all trash
+            continue
+        for j in range(end // page + 1):
+            bt[b, j] = free.pop()
+        pos[b] = end
+    return jnp.asarray(bt), jnp.asarray(pos)
+
+
+def _edge_case(KV, G, hd, page, nb, seed):
+    chunk = min(nb, pa.live_walk_chunk_pages(page, KV, hd, 4))
+    P = 1 + 6 * nb
+    ks = jax.random.split(jax.random.key(seed), 3)
+    q = jax.random.normal(ks[0], (6, KV, G, hd)) * 2.0
+    kp = jax.random.normal(ks[1], (P, KV, page, hd))
+    vp = jax.random.normal(ks[2], (P, KV, page, hd))
+    bt, pos = _edge_tables(np.random.RandomState(seed), nb, page, chunk, P)
+    return q, kp, vp, bt, pos
+
+
+@pytest.mark.parametrize("soft_cap", [0.0, 30.0])
+@pytest.mark.parametrize("KV,G,hd,page,nb", [
+    (1, 8, 64, 16, 40),       # MQA-style, C = 16: three chunks
+    (2, 2, 16, 8, 70),        # GQA, C = 32: three chunks
+    (8, 1, 32, 4, 24),        # MHA (G=1), page 4: one chunk of the table
+    (8, 2, 128, 16, 20),      # qwen3-0.6b heads, C = 16: two chunks
+])
+def test_live_walk_matches_reference(KV, G, hd, page, nb, soft_cap):
+    q, kp, vp, bt, pos = _edge_case(KV, G, hd, page, nb, KV * 31 + page)
+    kw = dict(scale=hd ** -0.5, soft_cap=soft_cap)
+    ref = pa.paged_attention_reference(q, kp, vp, bt, pos, **kw)
+    out = pa.paged_attention(q, kp, vp, bt, pos, interpret=True, **kw)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-6)
+
+
+def test_live_walk_never_reads_dead_pages():
+    """Trash and unused pool pages full of NaN, and VMEM scratch starting
+    as NaN: the walk copies only live pages and zeroes the V rows it did
+    not copy, so every live lane stays finite and matches the reference
+    over a clean pool."""
+    KV, G, hd, page, nb = 2, 2, 16, 16, 40
+    q, kp, vp, bt, pos = _edge_case(KV, G, hd, page, nb, 3)
+    live = slice(1, None)                     # lane 0 is idle: trash only
+    q, bt, pos = q[live], bt[live], pos[live]
+    used = np.zeros((kp.shape[0],), bool)
+    for b in range(bt.shape[0]):
+        used[np.asarray(bt[b, :int(pos[b]) // page + 1])] = True
+    dead = jnp.asarray(~used)[:, None, None, None]
+    kp_nan = jnp.where(dead, jnp.nan, kp)
+    vp_nan = jnp.where(dead, jnp.nan, vp)
+    out = pa.paged_attention(
+        q, kp_nan, vp_nan, bt, pos, scale=hd ** -0.5,
+        interpret=pltpu.InterpretParams(uninitialized_memory="nan"))
+    ref = pa.paged_attention_reference(q, kp, vp, bt, pos, scale=hd ** -0.5)
+    assert np.isfinite(np.asarray(out)).all()
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("page,KV,hd,itemsize,chunk", [
+    (16, 8, 128, 2, 16),      # qwen3-0.6b: 256 keys, 2 MiB of slabs
+    (16, 2, 128, 2, 16),      # its tp=4 shard
+    (4, 2, 16, 4, 64),
+    (16, 32, 256, 2, 4),      # wide heads: halved under the VMEM cap
+])
+def test_live_walk_chunk_pages(page, KV, hd, itemsize, chunk):
+    assert pa.live_walk_chunk_pages(page, KV, hd, itemsize) == chunk
+    slabs = 4 * KV * chunk * page * hd * itemsize
+    assert slabs <= pa.LIVE_WALK_VMEM_BYTES
+
+
+def test_live_walk_pages_counts_live_entries():
+    pos = np.array([0, 0, 15, 16, 4159, 9999], np.int32)
+    # idle and single-token lanes walk one page; the last is capped
+    assert pa.live_walk_pages(pos, 16, 260) == 1 + 1 + 1 + 2 + 260 + 260
+
+
+def test_live_walk_engages_only_off_and_unquantized():
+    assert pa.walks_live_pages(quantized=False, pipeline="off")
+    assert not pa.walks_live_pages(quantized=True, pipeline="off")
+    assert not pa.walks_live_pages(quantized=False, pipeline="double")
 
 
 @pytest.mark.parametrize("B,H,r,dr,page,nb", [
@@ -190,7 +288,7 @@ def test_gqa_pipeline_double_bitwise(B, KV, G, hd, page, nb):
     vp = jax.random.normal(ks[2], (P, KV, page, hd))
     bt, pos = _ragged_tables(np.random.RandomState(B), B, nb, page, P)
     kw = dict(scale=hd ** -0.5, soft_cap=25.0, interpret=True)
-    off = pa.paged_attention(q, kp, vp, bt, pos, **kw)
+    off = pa.paged_attention_blocks(q, kp, vp, bt, pos, **kw)
     dbl = pa.paged_attention(q, kp, vp, bt, pos, pipeline="double", **kw)
     np.testing.assert_array_equal(np.asarray(dbl), np.asarray(off))
     ref = pa.paged_attention_reference(q, kp, vp, bt, pos, scale=hd ** -0.5,

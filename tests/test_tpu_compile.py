@@ -155,6 +155,25 @@ def test_paged_kernel_compiles_for_v5e(one_chip, kind, verify, kv_dtype,
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("kv_heads", [8, 2], ids=["one-chip", "tp4-shard"])
+def test_live_walk_compiles_for_v5e_at_cell_widths(one_chip, kv_heads):
+    """The live-page decode walk at the docqa cell's widths (16 slots,
+    260-entry tables, 2816 pages), with all 8 KV heads of one chip and
+    with the 2 a tp=4 shard holds."""
+    B, n_blocks, num_pages = 16, 260, 2816
+    G, hd = GQA["G"], GQA["hd"]
+
+    def fn(q, bt, pos, k, v):
+        return pa.paged_attention(q, k, v, bt, pos, scale=hd ** -0.5,
+                                  interpret=False)
+
+    pool = ((num_pages, kv_heads, PAGE, hd), jnp.bfloat16)
+    compiled = _compile(fn, one_chip, ((B, kv_heads, G, hd), jnp.bfloat16),
+                        ((B, n_blocks), jnp.int32), ((B,), jnp.int32),
+                        pool, pool)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_qwen3_decode_step_compiles_and_fits_v5e(one_chip, monkeypatch):
     """One whole paged decode step of qwen3-0.6b at published widths, with
     the Pallas kernels as a TPU process would resolve them, fits one
