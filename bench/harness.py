@@ -7,10 +7,12 @@ configuration (``bench/configs/<config>.json``) and a traffic mix
 cell, a mix, a configuration or a metric is added with files and entries.
 
 The window drives ``Engine.step()`` of the engine ``repro.serve.make_engine``
-builds on one chip, with requests arriving through ``Engine.submit()``.
-The harness stamps each request's due time itself: in an open loop a
-request is timed from when it was due, not from when the loop got round
-to it.
+builds, with requests arriving through ``Engine.submit()``.  A
+configuration whose ``serving.tp`` is above 1 is served tensor-parallel
+over a ``(1, tp)`` mesh of the cell's chips, its weights born in their
+shards.  The harness stamps each request's due time itself: in an open
+loop a request is timed from when it was due, not from when the loop got
+round to it; in a backlog a request is due when it joins the queue.
 """
 
 from __future__ import annotations
@@ -40,6 +42,7 @@ TRACE_DIR = ROOT / ".bench_cache" / "trace"
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 CHECK_TOKENS = 256          # served tokens the reference re-reads per run
 WARMUP_NEW_TOKENS = 2       # one prefill token and one decode token
+RAMP_STEPS_PER_SLOT = 10    # a backlog's ramp gives up after this many
 
 
 def log(msg: str) -> None:
@@ -151,6 +154,7 @@ class RunData:
     setup_s: float
     records: List[Record]
     phases: Dict[str, Dict[str, float]]
+    t_traced: Optional[float] = None    # where the trace begins, if later
     trace: Optional[object] = None      # bench.trace.TraceSummary
     warmup_programs: int = 0            # compiled or loaded in warm-up
     warmup_lengths: int = 0             # distinct prompt lengths warmed
@@ -184,14 +188,15 @@ class RunData:
         return sum(1 for r in self.records for x in r.token_times
                    if self.t_open <= x <= self.t_end)
 
-    def decode_contexts(self) -> List[int]:
-        """Keys each decode token of the window attended to: token k >= 1
-        of a request with an n-token prompt reads n + k keys (token 0
-        comes from the prefill)."""
+    def decode_contexts(self, since: Optional[float] = None) -> List[int]:
+        """Keys each decode token of the window (from ``since`` on)
+        attended to: token k >= 1 of a request with an n-token prompt
+        reads n + k keys (token 0 comes from the prefill)."""
+        lo = self.t_open if since is None else since
         out = []
         for r in self.records:
             for k, x in enumerate(r.token_times):
-                if k >= 1 and self.t_open <= x <= self.t_end:
+                if k >= 1 and lo <= x <= self.t_end:
                     out.append(r.prompt_len + k)
         return out
 
@@ -252,6 +257,38 @@ def _engine_config(serving: Dict):
                         kernel_backend="pallas")
 
 
+def _build_engine(adapter, config: Dict, mcfg, seed: int):
+    """The seed's weights and the engine ``make_engine`` builds for them:
+    at ``tp`` 1 on one chip; above, over a ``(1, tp)`` mesh, with each
+    weight born in its shard."""
+    from repro.parallel.mesh import make_host_mesh
+    from repro.serve import make_engine
+    serving = config["serving"]
+    tp = serving["tp"]
+    mesh = make_host_mesh(data=1, model=tp) if tp > 1 else None
+    params = adapter.make_params(config, mcfg, seed, mesh)
+    jax.block_until_ready(params)
+    return make_engine(mcfg, params, _engine_config(serving),
+                       mesh_shape=(1, tp))
+
+
+def _fill_slots(engine, top_up, records: List[Record],
+                num_slots: int) -> None:
+    """Step a backlog, kept topped up, until every slot decodes: each of
+    the ``num_slots`` holds a request past its prefill."""
+    from repro.serve import RequestState
+    for _ in range(RAMP_STEPS_PER_SLOT * num_slots):
+        top_up(time.perf_counter())
+        running = sum(r.request is not None
+                      and r.request.state is RequestState.RUNNING
+                      for r in records)
+        if running >= num_slots:
+            return
+        engine.step()
+    raise RuntimeError(f"the backlog filled {running} of {num_slots} slots "
+                       f"in {RAMP_STEPS_PER_SLOT * num_slots} steps")
+
+
 def _warm_up(engine, lengths: List[int], seed: int, vocab: int):
     """Prefill every prompt length this run's schedule sends (the padded
     prefill buckets, and the page writes the program compiles for each
@@ -301,10 +338,14 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     a fault in the timed path before the window; ``control`` also holds
     the float8 control, in the served tokens' place, to the same check
     (under ``"control"``, which does not decide the run's ``correct``)."""
-    from repro.serve import GenerateConfig, RequestState, make_engine
+    from repro.serve import GenerateConfig, RequestState
 
     config, mix = cell.config, cell.mix
     serving = config["serving"]
+    if serving["tp"] != cell.chips:
+        raise ValueError(f"{cell.name}: serving.tp {serving['tp']} on "
+                         f"{cell.chips} chips; a cell serves one (1, tp) "
+                         f"mesh over all its chips")
     dev0 = jax.devices()[0]
     peaks = peaks_for(dev0.device_kind) if dev0.platform == "tpu" else {}
     adapter, reference = model_modules(config)
@@ -312,10 +353,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
 
     # -- set-up: weights, engine, warm-up -------------------------------
     mcfg = adapter.model_config(config)
-    params = adapter.make_params(config, mcfg, seed)
-    jax.block_until_ready(params)
-    engine = make_engine(mcfg, params, _engine_config(serving))
-    del params
+    engine = _build_engine(adapter, config, mcfg, seed)
     vocab = config["vocab_size"]
     tr = traffic_mod.Traffic(mix, seed, vocab, seconds)
     if tr.max_tokens > serving["max_len"]:
@@ -323,13 +361,12 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                          f"max_len is {serving['max_len']}")
     n0, s0 = compiles.count, compiles.seconds
     _warm_up(engine, tr.prompt_lengths, seed, vocab)
-    if engine_hook is not None:
-        engine_hook(engine)
-    engine.reset_phases()
     warmup_programs = compiles.count - n0
     log(f"warm-up: {len(tr.prompt_lengths)} prompt lengths, "
         f"{warmup_programs} compilations ({compiles.seconds - s0:.3f}s);"
         f" {compiles.count} ({compiles.seconds:.3f}s) in all set-up")
+    if engine_hook is not None:
+        engine_hook(engine)
 
     records: List[Record] = []
 
@@ -344,28 +381,54 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
             log(f"request {item.index} refused: {e}")
         records.append(rec)
 
-    if trace:
+    pending = list(tr.items)            # a Poisson schedule, due order
+    waiting: List[Record] = []          # a backlog's requests not yet placed
+
+    def top_up(t: float) -> None:
+        """Keep a backlog's ``depth`` requests submitted and not yet
+        placed; each newcomer is due at ``t``, when it joins the queue."""
+        waiting[:] = [r for r in waiting if not r.placed and not r.failed]
+        while len(waiting) < tr.depth:
+            submit(tr.draw(), t)
+            waiting.append(records[-1])
+
+    if tr.depth:
+        _fill_slots(engine, top_up, records, serving["num_slots"])
+    engine.reset_phases()
+
+    # a configuration whose trace would outlast a run profiles only the
+    # window's last ``trace_seconds``: the traced runs report per-layer
+    # metrics alone, none of them read from the untraced stretch
+    trace_s = min(seconds, config.get("trace_seconds", seconds))
+
+    def start_profiler() -> None:
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
         jax.profiler.start_trace(str(TRACE_DIR), profiler_options=opts)
+
+    if trace and trace_s == seconds:
+        start_profiler()
 
     # -- the window --------------------------------------------------------
     n_compiles0 = compiles.count
     t_open = time.perf_counter()
     setup_s = t_open - t_process
     deadline = t_open + seconds
-    pending = list(tr.items)            # due order
     TA = jax.profiler.TraceAnnotation
-    with TA("window"):
+
+    def drive(until: float) -> None:
         while True:
             t = time.perf_counter()
-            if t >= deadline:
+            if t >= until:
                 break
             with TA("generator.submit"):
-                while pending and t_open + pending[0].offset_s <= t:
-                    item = pending.pop(0)
-                    submit(item, t_open + item.offset_s)
+                if tr.depth:
+                    top_up(t)
+                else:
+                    while pending and t_open + pending[0].offset_s <= t:
+                        item = pending.pop(0)
+                        submit(item, t_open + item.offset_s)
             busy = any(r.request is not None
                        and r.request.state is not RequestState.FINISHED
                        for r in records)
@@ -373,14 +436,23 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                 with TA("engine.step"):
                     engine.step()
             else:
-                nxt = t_open + pending[0].offset_s if pending else deadline
+                nxt = t_open + pending[0].offset_s if pending else until
                 with TA("generator.wait"):
-                    time.sleep(max(0.0, min(nxt, deadline)
+                    time.sleep(max(0.0, min(nxt, until)
                                    - time.perf_counter()))
+
+    t_traced = t_open
+    if trace and trace_s < seconds:
+        drive(deadline - trace_s)
+        start_profiler()
+        t_traced = time.perf_counter()
+    with TA("window"):
+        drive(deadline)
     t_end = time.perf_counter()
     window_compiles = compiles.count - n_compiles0
     if trace:
         jax.profiler.stop_trace()
+        log(f"trace written in {time.perf_counter() - t_end:.3f}s")
     compiles.close()
 
     late = [r.submitted - r.due for r in records]
@@ -405,6 +477,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                   chips=cell.chips, num_slots=serving["num_slots"],
                   peaks=peaks, t_open=t_open, deadline=deadline, t_end=t_end,
                   setup_s=setup_s, records=records, phases=phases,
+                  t_traced=t_traced,
                   warmup_programs=warmup_programs,
                   warmup_lengths=len(tr.prompt_lengths))
 
@@ -420,9 +493,12 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     trace_summary = None
     if trace:
         from bench import trace as trace_mod
+        t0 = time.perf_counter()
         trace_summary = trace_mod.summarize(trace_mod.load(TRACE_DIR),
                                             cell.chips)
         run.trace = trace_summary
+        log(f"trace read in {time.perf_counter() - t0:.3f}s: ops per chip "
+            f"{[len(v) for v in trace_summary.ops.values()]}")
 
     metrics = {}
     for m in (cell.per_layer if trace else cell.end_to_end):

@@ -3,6 +3,7 @@
 that a later cell reports under a name of its own (``decode_step_ms.docqa``
 beside a ``decode_step_ms.<cell>``) shares its arithmetic here."""
 
+from bench import spans
 from bench.workcounts import (decode_token_flops, least_time_s,
                               paged_attention_work, prefill_flops)
 
@@ -36,12 +37,26 @@ def paged_attention_roofline(run):
     chip needs for the work the window's decode steps asked of it (every
     live K/V line read once, this chip's share of the KV heads, queries
     and outputs; or its FLOPs at peak, whichever is longer) over the
-    device time of the ``paged_attention`` kernel's ops on chip 0."""
+    device time of the ``paged_attention`` kernel's ops on chip 0, both
+    over the traced stretch.  A tensor-parallel chip holds ``1 /
+    serving.tp`` of the heads."""
     if run.trace is None or not run.peaks:
         return None
     measured = run.trace.kernel_time_s("paged_attention", chip=0)
-    contexts = run.decode_contexts()
+    contexts = run.decode_contexts(since=run.t_traced)
     if measured <= 0 or not contexts:
         return None
-    flops, nbytes = paged_attention_work(run.dims, contexts)
+    flops, nbytes = paged_attention_work(run.dims, contexts,
+                                         tp=run.config["serving"]["tp"])
     return 100.0 * least_time_s(flops, nbytes, run.peaks) / measured
+
+
+def collective_ms_per_step(run):
+    """Device time of the collectives (all-reduce, all-gather, ...) in the
+    decode step's program on chip 0, ms per decode step (``engine.decode``
+    span) of the traced stretch; None where it ran none."""
+    if run.trace is None:
+        return None
+    steps = sum(1 for sp in spans.spans_of(run) if spans.is_decode_step(sp))
+    t = run.trace.collective_time_s(chip=0, module="decode")
+    return 1e3 * t / steps if steps and t > 0 else None

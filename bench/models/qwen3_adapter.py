@@ -3,7 +3,9 @@ seed's weights laid out in the program's parameter tree.
 
 The weights are the reference's (``qwen3_reference``), drawn on the device
 in one jitted call, in the dtypes the program's parameter definitions ask
-for.
+for.  With a mesh, that call's outputs take the program's tensor-parallel
+layout (``repro.serve.shard.param_pspecs``): each device draws and keeps
+only its own shard, and the values are those of the one-device call.
 """
 
 from __future__ import annotations
@@ -12,6 +14,7 @@ from typing import Dict
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding
 
 from repro.models import abstract_params
 from repro.models.common import ModelConfig
@@ -47,9 +50,9 @@ def _keys(path):
     return tuple(getattr(p, "key", getattr(p, "idx", None)) for p in path)
 
 
-def make_params(cfg: Dict, mcfg: ModelConfig, seed: int):
+def make_params(cfg: Dict, mcfg: ModelConfig, seed: int, mesh=None):
     """The seed's weights in the program's tree, made on the device in one
-    jitted call."""
+    jitted call; with ``mesh``, born in their tensor-parallel shards."""
     shapes = abstract_params(mcfg)
     paths = [_keys(p) for p, _ in
              jax.tree_util.tree_flatten_with_path(shapes)[0]]
@@ -75,4 +78,9 @@ def make_params(cfg: Dict, mcfg: ModelConfig, seed: int):
         return jax.tree_util.tree_unflatten(
             jax.tree_util.tree_structure(shapes), flat)
 
-    return jax.jit(build)(ref.seed_key(seed))
+    placed = {}
+    if mesh is not None:
+        from repro.serve.shard import param_pspecs
+        placed["out_shardings"] = jax.tree.map(
+            lambda sp: NamedSharding(mesh, sp), param_pspecs(mcfg, mesh))
+    return jax.jit(build, **placed)(ref.seed_key(seed))

@@ -5,10 +5,12 @@ timed path sound, broken underneath, or replaced by the float8 control:
 import math
 import time
 
+import jax
 import jax.numpy as jnp
 import pytest
 
 from bench import harness
+from bench import trace as trace_mod
 from bench.tests.conftest import TINY_MIX, tiny_config
 
 CELL = "qwen3-0.6b.docqa-poisson"
@@ -84,3 +86,36 @@ def test_float8_control_fails_the_limit():
     res = _run(cfg, control=True)
     assert res["correct"], res["checks"]
     assert res["control"]["correct"] is False, res["control"]
+
+
+class _FakeSummary:
+    busy_s, window_s, ops = 1.0, 1.0, {0: []}
+
+    def breakdown(self):
+        return {"device_ops": [], "idle_gaps": []}
+
+
+@pytest.mark.parametrize("trace_seconds", [None, 2.0])
+def test_trace_seconds_profiles_the_windows_last_stretch(monkeypatch,
+                                                         trace_seconds):
+    """A configuration's ``trace_seconds`` starts the profiler that long
+    before the window closes; without it the whole window is traced."""
+    stamps = {}
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda *a, **k:
+                        stamps.setdefault("start", time.perf_counter()))
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda:
+                        stamps.setdefault("stop", time.perf_counter()))
+    monkeypatch.setattr(trace_mod, "load", lambda path: None)
+    monkeypatch.setattr(trace_mod, "summarize",
+                        lambda profile, chips: _FakeSummary())
+    cfg = tiny_config()
+    if trace_seconds is not None:
+        cfg["trace_seconds"] = trace_seconds
+    cell = harness.Cell(CELL, 1, cfg, dict(TINY_MIX), [], [])
+    seconds = 6.0
+    res = harness.run_cell(cell, 2**31 + 102, seconds, True,
+                           time.perf_counter())
+    assert res["correct"], res["checks"]
+    traced = stamps["stop"] - stamps["start"]
+    want = seconds if trace_seconds is None else trace_seconds
+    assert want - 1.0 < traced < want + 1.0

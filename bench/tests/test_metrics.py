@@ -5,7 +5,7 @@ import json
 import numpy as np
 import pytest
 
-from bench import harness, workcounts
+from bench import harness, spans, workcounts
 from bench.peaks import peaks_for
 
 CFG = json.loads((harness.BENCH / "configs" / "qwen3-0.6b.json").read_text())
@@ -27,6 +27,9 @@ class _Trace:
 
     def kernel_time_s(self, kernel, chip=0):
         return 2.0 if kernel == "paged_attention" else 0.0
+
+    def collective_time_s(self, chip=0, module=None):
+        return 0.3 if module == "decode" else 0.5
 
 
 def _run():
@@ -50,8 +53,11 @@ def _run():
         trace=_Trace(), warmup_programs=45, warmup_lengths=4)
 
 
-def read(name):
-    return harness.metric_reader(name)(_run())
+def read(name, **run):
+    r = _run()
+    for k, v in run.items():
+        setattr(r, k, v)
+    return harness.metric_reader(name)(r)
 
 
 def test_latencies():
@@ -81,6 +87,43 @@ def test_utilizations():
     flops, nbytes = workcounts.paged_attention_work(d, [11, 12, 31])
     want = 100 * max(flops / peak, nbytes / 819e9) / 2.0
     assert read("paged_attention_roofline.docqa") == pytest.approx(want)
+
+
+def test_backlog_rates_and_step_times(monkeypatch):
+    steps = [spans.HostSpan(0.0, 1.0, "engine.decode", {"batch": 2})] * 3
+    monkeypatch.setattr(spans, "spans_of", lambda run: steps)
+    # request 0's three tokens and request 2's one at 100.5, over 10 s
+    assert read("output_tok_s") == pytest.approx(0.4)
+    assert read("output_tok_s", records=[]) is None
+    assert read("decode_step_ms.backlog") == pytest.approx(500.0)
+    assert read("step_mfu.backlog") == pytest.approx(read("step_mfu.docqa"))
+    # 0.3 s of collectives in the decode program over the trace's 3
+    # decode steps
+    assert read("collective_ms_per_step.backlog") == pytest.approx(100.0)
+    assert read("collective_ms_per_step.backlog", trace=None) is None
+
+
+def test_roofline_reads_the_traced_stretch_only():
+    """With the trace begun at 102.2 s, only request 0's tokens at 102.5
+    and 103.5 (11 and 12 keys) are the kernel's work."""
+    d = workcounts.Dims.of(CFG)
+    flops, nbytes = workcounts.paged_attention_work(d, [11, 12])
+    want = 100 * max(flops / 197e12, nbytes / 819e9) / 2.0
+    assert read("paged_attention_roofline.docqa", t_traced=102.2) \
+        == pytest.approx(want)
+
+
+def test_roofline_counts_a_tensor_parallel_chips_share():
+    cfg = json.loads((harness.BENCH / "configs" /
+                      "qwen3-14b-tp4.json").read_text())
+    d = workcounts.Dims.of(cfg)
+    got = read("paged_attention_roofline.backlog", config=cfg, dims=d,
+               chips=4)
+    flops, nbytes = workcounts.paged_attention_work(d, [11, 12, 31], tp=4)
+    assert got == pytest.approx(100 * max(flops / 197e12, nbytes / 819e9)
+                                / 2.0)
+    whole, _ = workcounts.paged_attention_work(d, [11, 12, 31])
+    assert flops == pytest.approx(whole / 4)
 
 
 def test_every_metric_of_the_spec_has_a_reader():

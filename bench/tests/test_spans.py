@@ -144,7 +144,8 @@ def _traced_run(tmp_path, monkeypatch, text):
 READERS = {"host_idle_ms_per_step.docqa": 31.0,
            "prefill_stall_p90_ms.docqa": 36.0,
            "prefill_tail_ms": 24.0,
-           "decode_batch_mean.docqa": 3.0}
+           "decode_batch_mean.docqa": 3.0,
+           "decode_batch_mean.backlog": 3.0}
 
 
 def test_stats_survive_the_xspace():

@@ -37,7 +37,7 @@ def test_cell_finds_its_pieces(cell):
         if m["name"] != "setup_s":
             assert callable(harness.metric_reader(m["name"]))
     harness.model_modules(c.config)
-    assert c.chips == 1
+    assert c.chips == c.config["serving"]["tp"]
 
 
 def test_reference_imports_nothing_of_the_program():

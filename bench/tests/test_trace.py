@@ -74,3 +74,52 @@ def test_op_names_come_from_the_hlo_text():
         == "all-reduce.5"
     assert trace.COLLECTIVE.match("all-reduce.5")
     assert not trace.COLLECTIVE.match("fusion.3")
+
+
+PSUM = ("%psum.14 = bf16[64,1,5120]{2,0,1:T(8,128)(2,1)S(1)} all-reduce("
+        "%fusion.162), channel_id=1, replica_groups={{0,1,2,3}}")
+GATHER = ("%all-gather.6 = bf16[4,37984,64]{1,2,0:T(8,128)(2,1)S(1)} "
+          "all-gather(%convolution_bitcast_fusion), dimensions={0}")
+WHILE = ("%while.3 = (s32[]{:T(128)}, bf16[1,1024]{1,0:T(8,128)(2,1)}, "
+         "/*index=5*/bf16[28]{0}) while(%tuple.1), condition=%cond")
+
+
+def test_op_codes_come_from_the_hlo_text():
+    """A psum is named by JAX (``%psum.14``) and is an all-reduce: the
+    collectives are found by opcode, not by name."""
+    assert trace.op_name(PSUM) == "psum.14"
+    assert trace.op_code(PSUM) == "all-reduce"
+    assert trace.op_code(GATHER) == "all-gather"
+    assert trace.op_code(WHILE) == "while"
+    assert trace.op_code("window") == ""
+
+
+def test_collectives_of_a_tensor_parallel_trace():
+    """Two chips, each with a fusion and a psum in the decode program and
+    an all-gather in a prefill program: chip 0's collective time by
+    program, and busy time averaged over both chips."""
+    from bench import spans
+    ms = 1e6
+    fusion = "%fusion.1 = f32[8]{0} fusion(f32[8]{0} %p)"
+
+    def chip(shift):
+        return {"XLA Modules": [("jit__decode_sample(1)", 0.0, 50 * ms, {}),
+                                ("jit__prefill_bucket(2)", 60 * ms, 30 * ms,
+                                 {})],
+                "XLA Ops": [(fusion, 1 * ms, 10 * ms, {}),
+                            (PSUM, (11 + shift) * ms, 4 * ms, {}),
+                            (GATHER, 61 * ms, 2 * ms, {})]}
+    text = spans.text_proto([
+        ("/host:CPU", {"python3": [("window", 0.0, 100 * ms, {})]}),
+        ("/device:TPU:0", chip(0)), ("/device:TPU:1", chip(-5))])
+    import jax
+    s = trace.summarize(jax.profiler.ProfileData.from_text_proto(text),
+                        chips=2)
+    assert [o.opcode for o in s.ops[0]] == ["fusion", "all-reduce",
+                                            "all-gather"]
+    assert s.collective_time_s(0, module="decode") == pytest.approx(0.004)
+    assert s.collective_time_s(0) == pytest.approx(0.006)
+    # chip 1's psum overlaps its fusion: busy is the union
+    assert s.chip_busy_s(0) == pytest.approx(0.016)
+    assert s.chip_busy_s(1) == pytest.approx(0.012)
+    assert s.busy_s == pytest.approx(0.014)

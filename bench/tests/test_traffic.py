@@ -1,6 +1,7 @@
-"""The seeded traffic generator: one seed, one schedule; Poisson arrivals;
-lengths distributed as the mix file says."""
+"""The seeded traffic generator: one seed, one schedule; Poisson arrivals
+and a backlog; lengths distributed as the mix file says."""
 
+import hashlib
 import json
 import math
 import statistics
@@ -19,8 +20,17 @@ def _mix(name):
     return json.loads((BENCH / "traffic" / f"{name}.json").read_text())
 
 
-def _items(mix, seed, seconds=50.0, vocab=1000):
-    return traffic.Traffic(mix, seed, vocab, seconds).items
+POISSON = [m for m in MIXES if _mix(m)["arrival"] == "poisson"]
+BACKLOG = [m for m in MIXES if _mix(m)["arrival"] == "backlog"]
+
+
+def _items(mix, seed, seconds=50.0, vocab=1000, backlog_items=256):
+    """A Poisson schedule's items, or the first ``backlog_items`` a
+    backlog's queue takes."""
+    t = traffic.Traffic(mix, seed, vocab, seconds)
+    for _ in range(backlog_items if t.depth else 0):
+        t.draw()
+    return t.items
 
 
 @pytest.mark.parametrize("name", MIXES)
@@ -66,7 +76,7 @@ def test_lengths_match_the_mix_file(name):
                                                          rel=0.1)
 
 
-@pytest.mark.parametrize("name", MIXES)
+@pytest.mark.parametrize("name", POISSON)
 def test_arrivals_are_poisson(name):
     mix = _mix(name)
     rate, seconds = mix["rate_per_s"], 50.0
@@ -102,3 +112,59 @@ def test_prompt_lengths_are_the_schedules_own():
     assert t.prompt_lengths == sorted({len(x.prompt) for x in t.items})
     assert t.max_tokens == max(len(x.prompt) + x.max_new_tokens
                                for x in t.items)
+
+
+def test_poisson_items_are_those_recorded_before_backlogs_existed():
+    """The Poisson branch draws the same items from the same stream as
+    the generator did before it learnt backlogs (``data/``: index, due
+    time, prompt length, budget and a hash of the token ids)."""
+    from bench.tests.conftest import TINY_MIX
+    recorded = json.loads((BENCH / "tests" / "data" /
+                           "poisson_items.json").read_text())
+    for key, want in recorded.items():
+        name, seed, seconds, vocab = key.split("/")
+        mix = TINY_MIX if name == "tiny" else _mix(name)
+        got = [[x.index, repr(x.offset_s), len(x.prompt), x.max_new_tokens,
+                hashlib.sha256(x.prompt.tobytes()).hexdigest()[:16]]
+               for x in _items(mix, int(seed), float(seconds), int(vocab))]
+        assert got == want, key
+
+
+@pytest.mark.parametrize("name", BACKLOG)
+def test_backlog_draws_on_demand_all_due_at_once(name):
+    """A backlog draws an item when its queue takes one, never runs dry,
+    and its warm-up covers every length any item sends."""
+    mix = _mix(name)
+    t = traffic.Traffic(mix, SEED, 1000, 50.0)
+    assert t.depth == mix["depth"] > 0 and t.items == []
+    drawn = [t.draw() for _ in range(1000)]
+    assert drawn == t.items and [x.index for x in drawn] == list(range(1000))
+    assert all(x.offset_s == 0.0 for x in drawn)
+    pool = traffic.lognormal_quantiles(mix["prompt_tokens"],
+                                       mix["distinct_prompt_lengths"])
+    assert t.prompt_lengths == sorted(set(pool.tolist()))
+    assert {len(x.prompt) for x in drawn} == set(t.prompt_lengths)
+    assert max(len(x.prompt) + x.max_new_tokens for x in drawn) \
+        <= t.max_tokens
+
+
+@pytest.mark.parametrize("name", BACKLOG)
+def test_backlog_answers_are_a_stratified_set_every_depth_items(name):
+    """A window takes a backlog's items in order, so every ``depth`` of
+    them carry one draw from each of ``depth`` equal-probability slices
+    of the answer lengths: seeds differ in order, not in the work."""
+    mix = _mix(name)
+    spec, d = mix["output_tokens"], mix["depth"]
+    mu, sigma = math.log(spec["median"]), spec["sigma"]
+    nd = statistics.NormalDist()
+    lo = [spec["min"]] + [
+        math.floor(math.exp(mu + sigma * nd.inv_cdf(i / d)))
+        for i in range(1, d)]
+    hi = [math.ceil(math.exp(mu + sigma * nd.inv_cdf(i / d)))
+          for i in range(1, d)] + [spec["max"]]
+    for seed in (SEED, SEED + 1):
+        out = [x.max_new_tokens for x in _items(mix, seed, 50.0)]
+        for b in range(0, len(out) - d + 1, d):
+            block = sorted(out[b:b + d])
+            assert all(max(lo[i], spec["min"]) <= v <= min(hi[i], spec["max"])
+                       for i, v in enumerate(block)), (seed, b)

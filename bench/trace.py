@@ -17,9 +17,11 @@ From them:
 - busy: the union of a chip's operation intervals inside the window;
 - a kernel's time: summed durations of the ops whose name carries it
   (``paged_attention``);
-- collective time: summed durations of all-reduce, all-gather,
-  reduce-scatter, all-to-all and collective-permute ops, in the decode
-  step's program (``*decode*`` modules) or in all programs;
+- collective time: summed durations of the ops whose HLO opcode is
+  all-reduce, all-gather, reduce-scatter, all-to-all or
+  collective-permute (a ``psum`` is named ``%psum.14`` and is an
+  ``all-reduce``), in the decode step's program (``*decode*`` modules) or
+  in all programs;
 - breakdown: the ops that took the most time (containers left out, so
   no time counts twice), and the longest idle stretches of chip 0, each
   named by the harness span the host was in.
@@ -29,9 +31,10 @@ from __future__ import annotations
 
 import bisect
 import dataclasses
+import functools
 import re
 from pathlib import Path
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
@@ -42,15 +45,16 @@ HOST_SPANS = ("engine.step", "generator.submit", "generator.wait")
 CONTAINERS = ("while", "conditional", "call")
 COLLECTIVE = re.compile(r"^(all-reduce|all-gather|reduce-scatter|all-to-all"
                         r"|collective-permute)")
+OPCODE = re.compile(r"\s([a-z][a-z0-9-]*)\(")
 TOP = 10
 
 
-@dataclasses.dataclass(frozen=True)
-class Op:
+class Op(NamedTuple):
     start: float            # ns
     end: float
     name: str               # HLO op name, e.g. "paged_attention.9"
     module: str             # program name, e.g. "jit__decode_sample"
+    opcode: str = ""        # HLO opcode, e.g. "custom-call"
 
     @property
     def kind(self) -> str:
@@ -58,8 +62,20 @@ class Op:
         return re.sub(r"\.\d+$", "", self.name)
 
 
+@functools.lru_cache(maxsize=1 << 16)       # a trace repeats its op names
 def op_name(hlo_text: str) -> str:
     return hlo_text.split(" = ", 1)[0].lstrip("%").strip()
+
+
+@functools.lru_cache(maxsize=1 << 16)
+def op_code(hlo_text: str) -> str:
+    """The opcode of an op's HLO text: the first word followed by ``(``
+    after its shape (``all-reduce`` in ``%psum.14 = bf16[64,1,5120]{...}
+    all-reduce(%fusion.162), ...``); "" for a name with no HLO text."""
+    if " = " not in hlo_text:
+        return ""
+    m = OPCODE.search(hlo_text.split(" = ", 1)[1])
+    return m.group(1) if m else ""
 
 
 def union_ns(intervals: List[Tuple[float, float]], lo: float,
@@ -125,7 +141,7 @@ class TraceSummary:
         """Collective op time on ``chip``, in programs whose name holds
         ``module`` (all programs when None)."""
         return sum(o.end - o.start for o in self.ops[chip]
-                   if COLLECTIVE.match(o.name)
+                   if COLLECTIVE.match(o.opcode)
                    and (module is None or module in o.module)) * 1e-9
 
     def host_span_at(self, t: float) -> str:
@@ -188,9 +204,11 @@ def summarize(profile, chips: int) -> TraceSummary:
         lines = {line.name: list(line.events) for line in plane.lines}
         evs = lines.get(OPS_LINE, [])
         mods = _modules_of(evs, lines.get(MODULES_LINE, []))
-        ops[int(m.group(1))] = [
-            Op(float(ev.start_ns), float(ev.start_ns) + float(ev.duration_ns),
-               op_name(ev.name), mod) for ev, mod in zip(evs, mods)]
+        chip = ops[int(m.group(1))] = []
+        for ev, mod in zip(evs, mods):
+            s, text = float(ev.start_ns), ev.name
+            chip.append(Op(s, s + float(ev.duration_ns), op_name(text), mod,
+                           op_code(text)))
     if window is None:
         raise ValueError("the trace holds no 'window' span")
     if sorted(ops) != list(range(chips)):
